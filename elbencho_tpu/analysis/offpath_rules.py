@@ -1,8 +1,9 @@
 """off-path-guards: telemetry on worker hot paths stays a None test.
 
 Every observability hook on the storage/TPU hot path — ``--tracefile``
-spans, ``--slowops`` capture — is wired as a nullable handle
-(``self._tracer`` / ``self._slowops`` / ``ring.tracer``): when the
+spans, the per-phase stage spans, ``--slowops`` capture — is wired as a
+nullable handle (``self._tracer`` / ``self._stages`` / ``ctx.stages`` /
+``self._slowops``): when the
 feature is off the handle is None and the instrumentation must compile
 down to ONE ``x is None`` attribute test, never a call or attribute
 chain. This rule finds handle uses (any dotted chain *through* a
@@ -30,7 +31,8 @@ import ast
 from .core import Finding, dotted_name, parent, rule
 
 #: attribute names that carry a nullable telemetry handle
-HANDLE_ATTRS = frozenset({"_tracer", "_slowops", "tracer"})
+HANDLE_ATTRS = frozenset({"_tracer", "_slowops", "tracer", "_stages",
+                          "stages"})
 
 #: the worker hot-path modules this rule patrols
 HOT_PATH_DIRS = ("elbencho_tpu/workers", "elbencho_tpu/tpu")

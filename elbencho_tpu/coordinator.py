@@ -905,7 +905,12 @@ class Coordinator:
             f"{self._profile_seq:03d}_{phase.name.lower()}")
         try:
             import jax
-            jax.profiler.start_trace(trace_dir)
+            # JAX's Python tracer (on by default) would time every Python
+            # call of the host path the trace is meant to observe; the
+            # program's own stage spans name the host's time instead
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception as err:  # noqa: BLE001 - any failure fails the run
             raise WorkerException(
                 f"--tpuprofile: cannot start jax trace "

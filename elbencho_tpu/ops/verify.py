@@ -110,16 +110,23 @@ def fingerprint_block_pallas(block_u32) -> "tuple[int, int]":
     return int(s) & 0xFFFFFFFF, int(x) & 0xFFFFFFFF
 
 
+def check_fingerprint(got: "tuple[int, int]", want: "tuple[int, int]",
+                      file_offset: int) -> None:
+    """Raise ValueError when a block's on-device (sum, xor) fingerprint is
+    not the one the verify pattern gives for its file offset."""
+    if got != want:
+        raise ValueError(
+            f"on-device integrity check failed for block at offset "
+            f"{file_offset}: fingerprint (sum={got[0]:#x}, xor={got[1]:#x})"
+            f" != expected (sum={want[0]:#x}, xor={want[1]:#x})")
+
+
 def verify_block_on_device(block_u32, file_offset: int, length: int,
                            salt: int) -> "tuple[int, int]":
     """The block's (sum, xor) fingerprint; raises ValueError if the
     HBM-resident block does not match the verify pattern for its file
     offset."""
-    got_sum, got_xor = fingerprint_block_pallas(block_u32)
-    want_sum, want_xor = expected_fingerprint_host(file_offset, length, salt)
-    if got_sum != want_sum or got_xor != want_xor:
-        raise ValueError(
-            f"on-device integrity check failed for block at offset "
-            f"{file_offset}: fingerprint (sum={got_sum:#x}, xor={got_xor:#x})"
-            f" != expected (sum={want_sum:#x}, xor={want_xor:#x})")
-    return got_sum, got_xor
+    got = fingerprint_block_pallas(block_u32)
+    check_fingerprint(got, expected_fingerprint_host(file_offset, length,
+                                                     salt), file_offset)
+    return got

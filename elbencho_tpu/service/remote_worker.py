@@ -1320,6 +1320,8 @@ class RemoteWorker(Worker):
             result.get("EntLatHisto", {}))
         self.iops_latency_histo_rwmix = LatencyHistogram.from_dict(
             result.get("IOLatHistoRWMixRead", {}))
+        self.op_resident_histo = LatencyHistogram.from_dict(
+            result.get("OpResidentLatHisto", {}))
         self.tpu_transfer_bytes = result.get("TpuHbmBytes", 0)
         self.tpu_transfer_usec = result.get("TpuHbmUSec", 0)
         self.tpu_dispatch_usec = result.get("TpuHbmDispatchUSec", 0)

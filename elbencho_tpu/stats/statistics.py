@@ -93,6 +93,8 @@ class PhaseResults:
         self.iops_histo = LatencyHistogram()
         self.entries_histo = LatencyHistogram()
         self.iops_histo_rwmix = LatencyHistogram()
+        # storage submit -> block resident in HBM (JSON OpResidentLatHisto)
+        self.op_resident_histo = LatencyHistogram()
         self.cpu_stonewall = 0.0
         self.cpu_last_done = 0.0
         self.elapsed_usec_vec: "list[int]" = []
@@ -481,6 +483,7 @@ class Statistics:
             res.iops_histo.merge(w.iops_latency_histo)
             res.entries_histo.merge(w.entries_latency_histo)
             res.iops_histo_rwmix.merge(w.iops_latency_histo_rwmix)
+            res.op_resident_histo.merge(w.op_resident_histo)
             res.tpu_bytes += w.tpu_transfer_bytes
             res.tpu_usec += w.tpu_transfer_usec
             res.tpu_dispatch_usec += w.tpu_dispatch_usec
@@ -974,6 +977,10 @@ class Statistics:
         rec["ElapsedUSecList"] = res.elapsed_usec_vec
         rec["IOLatHisto"] = res.iops_histo.to_dict()
         rec["EntLatHisto"] = res.entries_histo.to_dict()
+        if res.op_resident_histo.num_values:
+            # per-op storage submit -> resident in HBM (TPU block loops);
+            # absent when no op of the phase landed in HBM
+            rec["OpResidentLatHisto"] = res.op_resident_histo.to_dict()
         if res.analysis is not None:
             # --flightrec run doctor: stage decomposition + bottleneck
             # verdict (docs/result-columns.md Analysis block); absent
@@ -1067,6 +1074,7 @@ class Statistics:
         iops_histo = LatencyHistogram()
         entries_histo = LatencyHistogram()
         iops_histo_rwmix = LatencyHistogram()
+        op_resident_histo = LatencyHistogram()
         final = {"entries": 0, "bytes": 0, "iops": 0}
         stonewall = {"entries": 0, "bytes": 0, "iops": 0}
         final_rwmix = {"entries": 0, "bytes": 0, "iops": 0}
@@ -1077,6 +1085,7 @@ class Statistics:
             iops_histo.merge(w.iops_latency_histo)
             entries_histo.merge(w.entries_latency_histo)
             iops_histo_rwmix.merge(w.iops_latency_histo_rwmix)
+            op_resident_histo.merge(w.op_resident_histo)
             final["entries"] += w.live_ops.num_entries_done
             final["bytes"] += w.live_ops.num_bytes_done
             final["iops"] += w.live_ops.num_iops_done
@@ -1109,6 +1118,7 @@ class Statistics:
             "IOLatHisto": iops_histo.to_dict(),
             "EntLatHisto": entries_histo.to_dict(),
             "IOLatHistoRWMixRead": iops_histo_rwmix.to_dict(),
+            "OpResidentLatHisto": op_resident_histo.to_dict(),
             "CPUUtilStoneWall": round(shared.cpu_util_stonewall, 1),
             "CPUUtil": round(shared.cpu_util_last_done, 1),
             "TpuHbmBytes": tpu_bytes,

@@ -1,15 +1,18 @@
 """Bounded ring-buffer per-op span recorder with Chrome trace output.
 
 ``--tracefile PATH`` arms the recorder; every instrumentation point in the
-workers (storage ops), the TPU transfer pipeline (dispatch-vs-DMA
-sub-spans) and the native stream ring (reap sub-spans) records one
-complete span ("ph": "X") per event. ``--tracesample R`` keeps only a
-probabilistic R fraction of op spans so long phases fit the ring.
+workers (storage ops), the TPU transfer pipeline and the fused stream
+loop (the per-op stage spans, ``StageSpans``) records one complete span
+("ph": "X") per event. ``--tracesample R`` keeps only a probabilistic R
+fraction of op spans so long phases fit the ring. The stage spans also
+go to the JAX profiler while a profiler session records
+(docs/telemetry.md "Stage spans").
 
 When tracing is OFF the recorder does not exist: workers hold
-``self._tracer is None`` and every instrumentation point is a single
-attribute test — no allocation, no call, no formatting (the overhead
-guard in tests/test_telemetry.py pins this).
+``self._tracer is None`` / ``self._stages is None`` and every
+instrumentation point is a single attribute test — no allocation, no
+call, no formatting (the overhead guards in tests/test_telemetry.py pin
+this).
 
 The output is Chrome trace-event JSON (the ``traceEvents`` array format),
 loadable in Perfetto / chrome://tracing; ``pid`` is the service's rank
@@ -95,6 +98,8 @@ class Tracer:
         # run trace id (master-minted, echoed by services) and — on
         # collected per-host files — the master-estimated clock offset
         self.extra_other_data: "dict" = {}
+        # per-op span ids: (rank, seq), unique over the ring's run
+        self.op_seqs = itertools.count(1)
 
     # -- recording -----------------------------------------------------------
 
@@ -159,12 +164,15 @@ class Tracer:
 
     def record_op(self, op: str, phase: str, start_ns: int, dur_usec: int,
                   rank: int, offset: int, size: int,
-                  slot: "int | None" = None) -> None:
-        """Storage-op span (the ISSUE's schema: phase, rank, op type,
-        offset, size, latency, staging slot). Subject to --tracesample."""
+                  slot: "int | None" = None, op_id=None) -> None:
+        """Storage-op span (phase, rank, op type, offset, size, latency,
+        staging slot, and the op id its stage spans share). Subject to
+        --tracesample."""
         args = {"phase": phase, "offset": offset, "size": size}
         if slot is not None:
             args["slot"] = slot
+        if op_id is not None:
+            args["op"] = op_id
         self.record(op, "io", start_ns, dur_usec, rank=rank, sampled=True,
                     **args)
 
@@ -208,6 +216,79 @@ class Tracer:
             },
         }
         atomic_write_json(self.path, doc)
+
+
+class StageSpans:
+    """One worker's per-phase sink for the stage spans of the
+    storage->HBM path: the Tracer ring (``--tracefile``), the JAX
+    profiler (a session recording at phase start: ``--tpuprofile``, the
+    benchmark's traced window), or both.
+
+    Stages are timed by the caller's own perf_counter_ns stamps, the ones
+    that feed the always-on stage counters, so a span covers exactly the
+    interval its counter books. ``begin`` opens a profiler annotation on
+    the calling thread; only leaf stages that wrap one blocking call are
+    opened there, so the device trace's idle gaps are named by what the
+    host was doing, never by an umbrella over it. Every span of one op
+    carries the op's id (``op`` in the ring's args); the op's root span
+    ``op`` runs from its storage submit to its last stage."""
+
+    def __init__(self, ring: "Tracer | None", rank: int, profiler: bool):
+        self.ring = ring
+        self.rank = rank
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+
+    def next_op(self) -> "tuple[int, int] | None":
+        """A fresh op id, (rank, seq); None when only the profiler
+        records (its annotations carry names alone)."""
+        if self.ring is None:
+            return None
+        return (self.rank, next(self.ring.op_seqs))
+
+    def begin(self, name: str):
+        """Open ``name`` on the profiler's timeline; the token for
+        ``end`` (None when only the ring records)."""
+        if self._annotation is None:
+            return None
+        token = self._annotation(name)
+        token.__enter__()
+        return token
+
+    def end(self, token, name: str, cat: str, start_ns: int, end_ns: int,
+            op=None, **args) -> None:
+        """Close a stage opened by ``begin`` and record it in the ring."""
+        if token is not None:
+            token.__exit__(None, None, None)
+        self.record(name, cat, start_ns, end_ns, op, **args)
+
+    def record(self, name: str, cat: str, start_ns: int, end_ns: int,
+               op=None, **args) -> None:
+        """A span in the ring only: stages that enclose other ops' work
+        (``reap_queue``), retroactive ones and the op's root span."""
+        if self.ring is None:
+            return
+        if op is not None:
+            args["op"] = op
+        self.ring.record(name, cat, start_ns, (end_ns - start_ns) // 1000,
+                         rank=self.rank, sampled=True, **args)
+
+
+def phase_stage_spans(ring: "Tracer | None", rank: int,
+                      device_path: bool) -> "StageSpans | None":
+    """The phase's stage-span handle, or None when neither --tracefile
+    nor a recording profiler wants the spans: every hook on the hot path
+    is then one ``is None`` test. The profiler is asked only on the
+    device path (a TPU context exists, so JAX is already loaded)."""
+    profiler = False
+    if device_path:
+        from jax.profiler import TraceAnnotation
+        profiler = bool(TraceAnnotation.is_enabled())
+    if ring is None and not profiler:
+        return None
+    return StageSpans(ring, rank, profiler)
 
 
 def make_tracer(cfg) -> "Tracer | None":

@@ -27,6 +27,8 @@ from __future__ import annotations
 
 import os
 import threading
+import time
+from array import array
 
 import numpy as np
 
@@ -93,6 +95,19 @@ PATH_AUDIT_COUNTERS = (
     ("slow_ops_recorded", "SlowOpsRecorded", "slow_ops_recorded"),
     ("op_samples_dropped", "OpSamplesDropped", "op_samples_dropped"),
     ("tail_p999_usec_hwm", "TailP999UsecHwm", "tail_p999_usec_hwm"),
+    # per-op stage counters of the storage->HBM path (docs/telemetry.md
+    # "Stage spans"), each sum with its own op count so a reader divides
+    # within one record: the time the host blocked in the transfer
+    # ring's completion wait; the on-device verify's sync (transfer +
+    # kernel) and the host's expected fingerprint; and, worker-owned,
+    # the wait of a reaped op behind the earlier ops of its reap batch
+    ("pipe_ready_wait_usec", "TpuReadyWaitUSec", "tpu_ready_wait_usec"),
+    ("pipe_ready_wait_ops", "TpuReadyWaitOps", "tpu_ready_wait_ops"),
+    ("verify_sync_usec", "TpuVerifySyncUSec", "tpu_verify_sync_usec"),
+    ("verify_ops", "TpuVerifyOps", "tpu_verify_ops"),
+    ("verify_expect_usec", "TpuVerifyExpectUSec", "tpu_verify_expect_usec"),
+    ("reap_queue_usec", "ReapQueueUSec", "reap_queue_usec"),
+    ("reap_queue_ops", "ReapQueueOps", "reap_queue_ops"),
 )
 
 #: counters owned by the Worker object itself rather than the
@@ -104,7 +119,8 @@ PATH_AUDIT_WORKER_ATTRS = frozenset({
     "pool_buf_reuses", "pool_occupancy_hwm", "pool_registered_ops",
     "pool_sqpoll_ops", "shard_ingest_mib", "ici_redist_mib",
     "ici_redist_usec", "ici_gbps_hwm", "slow_ops_recorded",
-    "op_samples_dropped", "tail_p999_usec_hwm"})
+    "op_samples_dropped", "tail_p999_usec_hwm", "reap_queue_usec",
+    "reap_queue_ops"})
 
 #: counters owned by the worker's StagingPool: the merge reads them
 #: from worker._staging_pool when one is attached (local workers), and
@@ -272,6 +288,13 @@ class TransferPipeline:
       fully-overlapped pipeline, ~ops when the ring is capacity-bound.
     - ``inflight_hwm``   in-flight high-water mark — proof the pipeline
       actually overlapped transfers (>= 2 under any real pipelining).
+    - ``ready_wait_usec`` / ``ready_wait_ops``  the time the host actually
+      blocked in a drain's completion wait, and the drains: unlike
+      ``transfer_usec`` these do not overlap, so their sum is host time.
+    - ``resident``       op-resident latencies (usec) not yet booked: from
+      the storage submit of an op carried by a ring entry (``op_ns``) to
+      the moment its drain returns; the worker moves them into its
+      histogram in bulk (``TpuWorkerContext.take_resident_usec``).
 
     ``budget_usec`` (--tpubudget): maximum average host-side dispatch
     cost per submitted op; check_budget() fails the run LOUDLY when the
@@ -282,32 +305,42 @@ class TransferPipeline:
         from collections import deque
         self.depth = max(depth, 1)
         self.budget_usec = max(budget_usec, 0)
-        self._ring = deque()  # (device array, submit-done perf_counter_ns)
+        # (device array, submit-done perf_counter_ns, the op's storage
+        # submit perf_counter_ns or 0, the op's span id or None)
+        self._ring = deque()
         self.dispatch_usec = 0
         self.transfer_usec = 0
         self.full_stalls = 0
         self.inflight_hwm = 0
         self.ops = 0
-        # --tracefile sub-span recorder (telemetry/tracer.py); None keeps
-        # the hot path a single attribute test per transfer
-        self.tracer = None
-        self.trace_rank = 0
+        self.ready_wait_usec = 0
+        self.ready_wait_ops = 0
+        self.resident = array("Q")
+        # per-phase stage-span handle (telemetry/tracer.py StageSpans);
+        # None keeps the hot path a single attribute test per transfer
+        self.stages = None
 
-    def submit(self, submit_fn):
+    def submit(self, submit_fn, op_ns: int = 0, op=None):
         """Issue one transfer (submit_fn() -> device array) into the ring,
         then drain to at most depth-1 in flight: with io_depth rotating
         host buffers, the buffer reused next is then guaranteed drained
-        (depth == 1 -> fully synchronous, per-block latency honest)."""
-        import time
+        (depth == 1 -> fully synchronous, per-block latency honest).
+        ``op_ns`` is the storage-submit stamp of the op whose block this
+        is, when its drain is where the block becomes resident (0: not
+        booked here); ``op`` the op's span id."""
+        st = self.stages
+        token = st.begin("tpu_dispatch") if st is not None else None
         t0 = time.perf_counter_ns()
         arr = submit_fn()
         t1 = time.perf_counter_ns()
         self.dispatch_usec += (t1 - t0) // 1000
         self.ops += 1
-        if self.tracer is not None:
-            self.tracer.record("tpu_dispatch", "tpu", t0, (t1 - t0) // 1000,
-                               rank=self.trace_rank, sampled=True)
-        self._ring.append((arr, t1))
+        if st is not None:
+            st.end(token, "tpu_dispatch", "tpu", t0, t1, op)
+        # an op whose residency is booked elsewhere (its on-device verify
+        # syncs on it) keeps its id off the entry: the entry's later drain
+        # is then no stage of that op
+        self._ring.append((arr, t1, op_ns, op if op_ns else None))
         if len(self._ring) > self.inflight_hwm:
             self.inflight_hwm = len(self._ring)
         while len(self._ring) >= self.depth:
@@ -320,19 +353,17 @@ class TransferPipeline:
         covers both directions."""
         self.dispatch_usec += usec
         self.ops += 1
-        if self.tracer is not None:
-            self.tracer.record("tpu_dispatch", "tpu",
-                               self.tracer.now_ns() - usec * 1000, usec,
-                               rank=self.trace_rank, sampled=True)
+        if self.stages is not None:
+            now = time.perf_counter_ns()
+            self.stages.record("tpu_dispatch", "tpu", now - usec * 1000, now)
 
     def note_transfer(self, usec: int) -> None:
         """Account DMA wall time of a transfer completed outside the ring
         (blocking D2H export waits)."""
         self.transfer_usec += usec
-        if self.tracer is not None:
-            self.tracer.record("tpu_dma", "tpu",
-                               self.tracer.now_ns() - usec * 1000, usec,
-                               rank=self.trace_rank, sampled=True)
+        if self.stages is not None:
+            now = time.perf_counter_ns()
+            self.stages.record("tpu_dma", "tpu", now - usec * 1000, now)
 
     def _drain_one(self, count_stall: bool = False) -> None:
         """Complete the oldest in-flight transfer. A full-ring drain
@@ -341,19 +372,26 @@ class TransferPipeline:
         already-ready entries and must read as zero stalls, not ~100%.
         Arrays without is_ready (foreign device types) count
         conservatively as stalled."""
-        import time
-        arr, t_submit = self._ring.popleft()
+        arr, t_submit, op_ns, op = self._ring.popleft()
         if count_stall:
             is_ready = getattr(arr, "is_ready", None)
             if is_ready is None or not is_ready():
                 self.full_stalls += 1
+        st = self.stages
+        token = st.begin("h2d_wait") if st is not None else None
+        t_wait = time.perf_counter_ns()
         arr.block_until_ready()
         done_ns = time.perf_counter_ns()
         self.transfer_usec += (done_ns - t_submit) // 1000
-        if self.tracer is not None:
-            self.tracer.record("tpu_dma", "tpu", t_submit,
-                               (done_ns - t_submit) // 1000,
-                               rank=self.trace_rank, sampled=True)
+        self.ready_wait_usec += (done_ns - t_wait) // 1000
+        self.ready_wait_ops += 1
+        if op_ns:
+            self.resident.append((done_ns - op_ns) // 1000)
+        if st is not None:
+            st.end(token, "h2d_wait", "tpu", t_wait, done_ns, op)
+            st.record("tpu_dma", "tpu", t_submit, done_ns, op)
+            if op is not None:
+                st.record("op", "op", op_ns, done_ns, op)
 
     def flush(self, check_budget: bool = True) -> None:
         """Drain every in-flight transfer (phase-end completion wait); by
@@ -400,6 +438,9 @@ class TransferPipeline:
         self.full_stalls = 0
         self.inflight_hwm = 0
         self.ops = 0
+        self.ready_wait_usec = 0
+        self.ready_wait_ops = 0
+        self.resident = array("Q")
 
 
 class TpuWorkerContext:
@@ -468,6 +509,7 @@ class TpuWorkerContext:
         self.pipeline_depth = min(self.pipeline_depth, max_depth)
         self._h2d_agg = None
         self._h2d_agg_fill = 0  # words staged in the active agg buffer
+        self._h2d_agg_op = (0, None)  # (op_ns, op) of the span's oldest op
         self._own_pool = None   # private allocator when no worker pool
         if self.batch_blocks > 1:
             # page-aligned host aggregation buffers (64B-aligned for the
@@ -554,6 +596,18 @@ class TpuWorkerContext:
         # PATH_AUDIT_COUNTERS): ops whose storage I/O ran in the engine's
         # submission/completion ring
         self.stream_fused_ops = 0
+        # on-device verify stage counters (PATH_AUDIT_COUNTERS)
+        self.verify_sync_usec = 0
+        self.verify_expect_usec = 0
+        self.verify_ops = 0
+        # the op the next host_to_device lands: its storage-submit
+        # perf_counter_ns (0: no resident sample) and its span id; set by
+        # the worker's block loops, a plain attribute so host_to_device
+        # keeps its signature
+        self.op_submit_ns = 0
+        self.op_id = None
+        # per-phase stage-span handle (telemetry/tracer.py StageSpans)
+        self.stages = None
         # --tpufallback: chip-failover audit + host-staging degraded mode.
         # chip_failovers is per-phase (PATH_AUDIT_COUNTERS); the
         # host-staging latch persists for the run — a lost chip stays
@@ -708,17 +762,51 @@ class TpuWorkerContext:
             # also means the dlpack stability contract moves to the
             # aggregation buffer (drained before reuse via the ring).
             start = self._h2d_agg_fill
+            if not start:
+                # the span's residency is booked for its oldest op
+                self._h2d_agg_op = (self.op_submit_ns, self.op_id)
             self._h2d_agg[start:start + n_words] = np_view
             self._h2d_agg_fill = start + n_words
             if self._h2d_agg_fill + self._num_words > len(self._h2d_agg):
                 self._flush_h2d_batch()
             return
-        self._transfer_h2d(np_view)
         if verify_salt and self.verify_on_device:
-            from ..ops.verify import verify_block_on_device
-            fp = verify_block_on_device(self._last_ingested, file_offset,
-                                        length, verify_salt)
-            fold_fingerprint(self, *fp)
+            # the verify's sync is where this block becomes resident
+            self._transfer_h2d(np_view, 0, self.op_id)
+            fold_fingerprint(self, *self._verify_ingested(
+                file_offset, length, verify_salt))
+        else:
+            self._transfer_h2d(np_view, self.op_submit_ns, self.op_id)
+
+    def _verify_ingested(self, file_offset: int, length: int,
+                         salt: int) -> "tuple[int, int]":
+        """The on-device verify of the block just ingested, timed in two
+        stages: the fingerprint step up to its host sync (the transfer
+        and the kernel), then the host's expected value. The sync's return
+        is the op's resident moment."""
+        from ..ops import verify
+        st = self.stages
+        op = self.op_id
+        token = st.begin("verify_sync") if st is not None else None
+        t0 = time.perf_counter_ns()
+        got = verify.fingerprint_block_pallas(self._last_ingested)
+        t1 = time.perf_counter_ns()
+        if st is not None:
+            st.end(token, "verify_sync", "tpu", t0, t1, op)
+            token = st.begin("verify_expect")
+        want = verify.expected_fingerprint_host(file_offset, length, salt)
+        t2 = time.perf_counter_ns()
+        self.verify_sync_usec += (t1 - t0) // 1000
+        self.verify_expect_usec += (t2 - t1) // 1000
+        self.verify_ops += 1
+        if self.op_submit_ns:
+            self._pipeline.resident.append((t1 - self.op_submit_ns) // 1000)
+        if st is not None:
+            st.end(token, "verify_expect", "tpu", t1, t2, op)
+            if op is not None:
+                st.record("op", "op", self.op_submit_ns, t2, op)
+        verify.check_fingerprint(got, want, file_offset)
+        return got
 
     #: read access to the pipeline's ring for tests/diagnostics (the ring
     #: discipline itself lives in TransferPipeline)
@@ -735,6 +823,20 @@ class TpuWorkerContext:
         return self._pipeline.inflight_hwm
 
     @property
+    def pipe_ready_wait_usec(self) -> int:
+        return self._pipeline.ready_wait_usec
+
+    @property
+    def pipe_ready_wait_ops(self) -> int:
+        return self._pipeline.ready_wait_ops
+
+    def take_resident_usec(self) -> array:
+        """The op-resident latencies (usec) booked since the last call;
+        the pipeline starts a fresh buffer."""
+        vals, self._pipeline.resident = self._pipeline.resident, array("Q")
+        return vals
+
+    @property
     def dispatch_usec(self) -> int:
         """Host-side submit cost this phase (both directions)."""
         return self._pipeline.dispatch_usec
@@ -744,17 +846,19 @@ class TpuWorkerContext:
         """DMA wall time this phase (both directions)."""
         return self._pipeline.transfer_usec
 
-    def _transfer_h2d(self, np_view: np.ndarray) -> None:
+    def _transfer_h2d(self, np_view: np.ndarray, op_ns: int = 0,
+                      op=None) -> None:
         """One DMA into the in-flight pipeline (a block, or a --tpubatch
         aggregation span). The staged path recycles per-slot HBM staging
         buffers through a donating jitted copy (see _staged_submit); the
-        direct path imports the host buffer as-is (zero-bounce)."""
+        direct path imports the host buffer as-is (zero-bounce). op_ns
+        and op: see TransferPipeline.submit."""
         if self.direct and self._h2d_direct_ok:
             arr = self._pipeline.submit(
-                lambda: self._direct_import(np_view))
+                lambda: self._direct_import(np_view), op_ns, op)
         else:
             arr = self._pipeline.submit(
-                lambda: self._staged_submit(np_view))
+                lambda: self._staged_submit(np_view), op_ns, op)
         self._last_ingested = arr  # keep resident (benchmark sink)
 
     def _staged_submit(self, np_view: np.ndarray):
@@ -813,7 +917,8 @@ class TpuWorkerContext:
 
     def _flush_h2d_batch(self) -> None:
         if self._h2d_agg_fill:
-            self._transfer_h2d(self._h2d_agg[:self._h2d_agg_fill])
+            self._transfer_h2d(self._h2d_agg[:self._h2d_agg_fill],
+                               *self._h2d_agg_op)
             # rotate to the next aggregation buffer: the one just
             # transferred may stay aliased by a direct import until the
             # ring drains it (by then the rotation has cycled past it)
@@ -871,11 +976,12 @@ class TpuWorkerContext:
             return max(self.pipeline_depth - 1, 0)
         return 0
 
-    def set_tracer(self, tracer, rank: int) -> None:
-        """Arm --tracefile dispatch-vs-DMA sub-spans on this context's
-        transfer pipeline (telemetry/tracer.py; no-op path untouched)."""
-        self._pipeline.tracer = tracer
-        self._pipeline.trace_rank = rank
+    def set_stages(self, stages) -> None:
+        """Arm (or, with None, disarm) the phase's stage spans on this
+        context and its transfer pipeline (telemetry/tracer.py
+        StageSpans)."""
+        self.stages = stages
+        self._pipeline.stages = stages
 
     def drain_to(self, max_inflight: int) -> None:
         """Drain the in-flight transfer ring to at most max_inflight

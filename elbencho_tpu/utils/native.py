@@ -96,17 +96,27 @@ def _as_u64_ptr(values, n):
 
 
 def _account_chunk(worker, lat_arr, lengths_np, n: int, bytes_done: int,
-                   total_bytes: int, op_is_read) -> None:
+                   total_bytes: int, op_is_read, done=None) -> None:
     """Post-chunk accounting shared by the block and mmap loops: on a
     complete chunk, latencies and counters are attributed exactly (split
-    into the rwmix-read counters when per-op flags are present); on an
-    interrupted chunk, completions can be out of order (AIO), so only the
-    done-prefix estimate is booked and latencies are skipped — with flags
-    the prefix split keeps the read/write ratio roughly right (exact for
-    the in-order sync/mmap paths)."""
+    into the rwmix-read counters when per-op flags are present). On an
+    interrupted chunk, ``done`` (a byte per op, 1 where it completed; the
+    fused stream loop knows it) books exactly the completed ops, their
+    latencies included. Without it, completions can be out of order
+    (AIO), so only the done-prefix estimate is booked and latencies are
+    skipped — with flags the prefix split keeps the read/write ratio
+    roughly right (exact for the in-order sync/mmap paths)."""
     import numpy as np
-    if bytes_done == total_bytes:
+    if bytes_done == total_bytes or done is not None:
         lat = np.frombuffer(lat_arr, dtype=np.uint64)
+        if bytes_done != total_bytes:
+            mask = np.frombuffer(done, dtype=np.bool_)
+            lat, lengths_np = lat[mask], lengths_np[mask]
+            if op_is_read is not None:
+                op_is_read = op_is_read[mask]
+            n_done = len(lat)
+        else:
+            n_done = n
         if op_is_read is not None and op_is_read.any():
             rd = op_is_read.astype(bool)
             worker.iops_latency_histo_rwmix.add_latencies_array(lat[rd])
@@ -115,26 +125,26 @@ def _account_chunk(worker, lat_arr, lengths_np, n: int, bytes_done: int,
             read_bytes = int(lengths_np[rd].sum())
             worker.live_ops_rwmix_read.num_iops_done += n_read
             worker.live_ops_rwmix_read.num_bytes_done += read_bytes
-            worker.live_ops.num_iops_done += n - n_read
-            worker.live_ops.num_bytes_done += total_bytes - read_bytes
+            worker.live_ops.num_iops_done += n_done - n_read
+            worker.live_ops.num_bytes_done += bytes_done - read_bytes
         else:
             worker.iops_latency_histo.add_latencies_array(lat)
-            worker.live_ops.num_iops_done += n
+            worker.live_ops.num_iops_done += n_done
             worker.live_ops.num_bytes_done += bytes_done
     else:
         avg_len = max(total_bytes // n, 1)
-        done = min(n, bytes_done // avg_len)
-        if op_is_read is not None and done:
-            rd = op_is_read[:done].astype(bool)
+        done_ops = min(n, bytes_done // avg_len)
+        if op_is_read is not None and done_ops:
+            rd = op_is_read[:done_ops].astype(bool)
             n_read = int(rd.sum())
-            read_bytes = int(lengths_np[:done][rd].sum())
+            read_bytes = int(lengths_np[:done_ops][rd].sum())
             worker.live_ops_rwmix_read.num_iops_done += n_read
             worker.live_ops_rwmix_read.num_bytes_done += read_bytes
-            worker.live_ops.num_iops_done += done - n_read
+            worker.live_ops.num_iops_done += done_ops - n_read
             worker.live_ops.num_bytes_done += \
                 max(bytes_done - read_bytes, 0)
         else:
-            worker.live_ops.num_iops_done += done
+            worker.live_ops.num_iops_done += done_ops
             worker.live_ops.num_bytes_done += bytes_done
     worker._num_iops_submitted += n
     pool = getattr(worker, "_staging_pool", None)
@@ -265,10 +275,6 @@ class NativeStream:
         self._out_lat = (ctypes.c_uint64 * max_ev)()
         self._out_res = (ctypes.c_int64 * max_ev)()
         self._max_events = max_ev
-        # --tracefile stream-reap sub-spans (telemetry/tracer.py); None
-        # keeps reap() free of any per-call trace work
-        self.tracer = None
-        self.trace_rank = 0
 
     def submit(self, slot: int, fd_idx: int, offset: int, length: int,
                is_write: bool) -> None:
@@ -286,21 +292,12 @@ class NativeStream:
         mid-stream surfaces with its slot context."""
         interrupt = (interrupt_flag if interrupt_flag is not None
                      else ctypes.c_int(0))
-        tracer = self.tracer
-        t0 = tracer.now_ns() if tracer is not None else 0
         got = self._lib.ioengine_stream_reap(
             self._handle, min_complete, timeout_msecs, self._out_slots,
             self._out_lat, self._out_res, self._max_events,
             ctypes.byref(interrupt))
         if got < 0:
             raise NativeStreamError(-got, "stream reap")
-        if tracer is not None:
-            # reap sub-span: how long the worker sat in the engine's
-            # completion wait, and how many storage ops it harvested
-            tracer.record("stream_reap", "stream", t0,
-                          (tracer.now_ns() - t0) // 1000,
-                          rank=self.trace_rank, sampled=True,
-                          events=got, min_complete=min_complete)
         return [(self._out_slots[i], self._out_lat[i], self._out_res[i])
                 for i in range(got)]
 

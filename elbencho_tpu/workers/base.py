@@ -120,6 +120,14 @@ class Worker:
         self.slow_ops_recorded = 0
         self.op_samples_dropped = 0
         self.tail_p999_usec_hwm = 0
+        # per-op stage counters of the storage->HBM path (worker-owned
+        # PATH_AUDIT_COUNTERS entries): a reaped op's wait behind the
+        # earlier ops of its reap batch, and the ops that waited
+        self.reap_queue_usec = 0
+        self.reap_queue_ops = 0
+        # storage submit -> block resident in HBM, per H2D op of the
+        # block loops (JSON OpResidentLatHisto)
+        self.op_resident_histo = LatencyHistogram()
         # --slowops per-worker recorder; None keeps every instrumentation
         # point a single attribute test (telemetry/slowops.py contract)
         from ..telemetry.slowops import make_recorder
@@ -175,6 +183,9 @@ class Worker:
         self.slow_ops_recorded = 0
         self.op_samples_dropped = 0
         self.tail_p999_usec_hwm = 0
+        self.reap_queue_usec = 0
+        self.reap_queue_ops = 0
+        self.op_resident_histo.reset()
         if self._slowops is not None:
             self._slowops.reset_phase()
 

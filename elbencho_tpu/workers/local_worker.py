@@ -76,6 +76,9 @@ class LocalWorker(Worker):
         # captured tail op can name its file (dir mode sets it per file;
         # file/bdev mode falls back to the first bench path)
         self._slowop_path = ""
+        # the phase's stage-span handle (telemetry/tracer.py StageSpans):
+        # None unless --tracefile or a recording profiler wants the spans
+        self._stages = None
         import ctypes
         self._native_interrupt = ctypes.c_int(0)  # seen by the C++ engine
 
@@ -98,6 +101,30 @@ class LocalWorker(Worker):
         if self._staging_pool is not None:
             # pool audit counters are per-phase; the POOL persists
             self._staging_pool.reset_counters()
+        # stage spans are armed per phase: the profiler sink only when a
+        # profiler session records now (--tpuprofile, a traced window)
+        from ..telemetry.tracer import phase_stage_spans
+        self._stages = phase_stage_spans(self._tracer, self.rank,
+                                         device_path=self._tpu is not None)
+        if self._tpu is not None:
+            self._tpu.set_stages(self._stages)
+
+    def finish_phase_stats(self) -> None:
+        if self._tpu is not None:
+            # a phase cut by --timelimit leaves transfers in flight:
+            # complete them, so that each H2D op of the phase books its
+            # ready wait and resident latency in this phase's record
+            self._tpu_guarded(self._tpu.drain_to, 0)
+            self._book_resident()
+        super().finish_phase_stats()
+
+    def _book_resident(self) -> None:
+        """Move the context's op-resident latencies into the histogram,
+        in bulk."""
+        vals = self._tpu.take_resident_usec()
+        if vals:
+            self.op_resident_histo.add_latencies_array(
+                np.frombuffer(vals, dtype=np.uint64))
 
     # ------------------------------------------------------------------
     # preparation (reference: preparePhase, LocalWorker.cpp:424)
@@ -146,9 +173,6 @@ class LocalWorker(Worker):
                 staging_pool=self._staging_pool)
             self.tpu_device_stamp = device_stamp(tpu_devices(),
                                                  self._tpu.device)
-            if self._tracer is not None:
-                # dispatch-vs-DMA sub-spans ride the transfer pipeline
-                self._tpu.set_tracer(self._tracer, self.rank)
             needs_fill = (cfg.run_create_files
                           or (cfg.run_tpu_bench
                               and cfg.tpu_bench_pattern in ("d2h", "both")))
@@ -931,8 +955,10 @@ class LocalWorker(Worker):
             if self._ops_log:
                 self._ops_log.log_op("read" if do_read_this_op else "write",
                                      "", real_off, length)
+            op = self._stages.next_op() if self._stages is not None \
+                else None
             if do_read_this_op:
-                self._post_read_actions(buf, real_off, length)
+                self._post_read_actions(buf, real_off, length, t0, op)
             elif cfg.do_read_inline or cfg.do_direct_verify:
                 self._inline_read_back(fd, buf, real_off, length)
             ops = (self.live_ops_rwmix_read
@@ -946,7 +972,7 @@ class LocalWorker(Worker):
                     "read" if do_read_this_op else "write",
                     phase_name(self.shared.current_phase), t0, lat_usec,
                     self.rank, real_off, length,
-                    slot=self._num_iops_submitted % num_bufs)
+                    slot=self._num_iops_submitted % num_bufs, op_id=op)
             if self._slowops is not None:  # no-op path: one attribute test
                 self._slowops.record(
                     "read" if do_read_this_op else "write",
@@ -975,6 +1001,7 @@ class LocalWorker(Worker):
             # in-flight transfer of a dying chip surfaces here)
             self._tpu_guarded(self._tpu.flush)
             self._sync_tpu_usec()
+            self._book_resident()
 
     def _sync_tpu_usec(self) -> None:
         """Mirror the context's split timing counters into this worker's
@@ -1242,9 +1269,6 @@ class LocalWorker(Worker):
             # test-only deterministic fault injection; config validation
             # already rejected this knob outside a test harness
             stream.set_fault_from_spec(fault_spec)
-        if self._tracer is not None:  # stream-reap sub-spans (--tracefile)
-            stream.tracer = self._tracer
-            stream.trace_rank = self.rank
         # slot-reuse discipline: a slot is free, in the engine ring
         # (slot_op), or held back after its H2D until the transfer ring
         # provably drained its zero-copy import (holdback_depth). The
@@ -1287,13 +1311,18 @@ class LocalWorker(Worker):
                             slot_op, hold) -> None:
         """One bounded chunk of the fused loop: submit every op (reaping
         for slots as needed), then drain to a chunk barrier so the
-        array-based accounting is exact; an interrupt books the
-        completed-prefix estimate before propagating (the same contract
-        as the interrupted native block loop)."""
+        array-based accounting is exact; an interrupt books exactly the
+        ops the ring completed before propagating.
+
+        Per op, a storage-submit stamp rides in its slot_op entry into
+        the transfer ring (the op-resident latency), and the wait from
+        its reap to its H2D dispatch books ReapQueueUSec; with stage
+        spans armed, the op's spans share one id."""
         import ctypes
         from ..utils.native import _account_chunk
         cfg = self.cfg
         ctx = self._tpu
+        st = self._stages
         offsets, lengths = batch
         n = len(offsets)
         if n == 0:
@@ -1306,10 +1335,10 @@ class LocalWorker(Worker):
                       else np.asarray(lengths, dtype=np.uint64))
         total = int(lengths_np.sum())
         lat_arr = (ctypes.c_uint64 * n)()
+        done = bytearray(n)  # 1 per op the ring completed
         state = {"bytes": 0}
 
-        def retry_or_raise(slot, i, fdi, r_off, length, rd, attempts,
-                           err) -> bool:
+        def retry_or_raise(slot, entry, err) -> bool:
             """--ioretries for a failed fused-ring op: backoff, then
             re-submit the SAME op on the SAME slot (the slot buffer still
             holds the write source; a read retries into it). attempts is
@@ -1319,6 +1348,7 @@ class LocalWorker(Worker):
             was submitted, raises the original error when retries are
             off/exhausted/not applicable."""
             from .io_errors import IoRetryBudgetExhausted, ShortIOError
+            i, fdi, r_off, length, rd, attempts, t_sub, op = entry
             retrier = self._io_retrier
             if retrier is None or not retrier.should_retry(
                     err, path=self._retry_path_hint(), attempt=attempts):
@@ -1329,14 +1359,26 @@ class LocalWorker(Worker):
                 retrier.backoff(attempt=attempts)
             except IoRetryBudgetExhausted:
                 raise err from None
-            slot_op[slot] = (i, fdi, r_off, length, rd, attempts + 1)
+            # the op keeps its first submit stamp: its resident latency
+            # includes the retries
+            slot_op[slot] = (i, fdi, r_off, length, rd, attempts + 1,
+                             t_sub, op)
             stream.submit(slot, fdi, r_off, length, is_write=not rd)
             return True
 
         def reap_some(min_complete: int) -> None:
             from .io_errors import ShortIOError
+            token = st.begin("stream_reap") if st is not None else None
+            t_reap = time.perf_counter_ns() if st is not None else 0
             events = stream.reap(min_complete, 1000,
                                  self._native_interrupt)
+            t_reaped = time.perf_counter_ns()
+            if st is not None:
+                # the reap's wait, and the ops it harvested
+                st.end(token, "stream_reap", "stream", t_reap, t_reaped,
+                       events=len(events), min_complete=min_complete,
+                       ops=[slot_op[e[0]][7] for e in events
+                            if e[0] in slot_op])
             if self._staging_pool is not None:
                 # registration/SQPOLL audit (PoolRegisteredOps and co)
                 self._staging_pool.account_stream_events(stream,
@@ -1361,23 +1403,23 @@ class LocalWorker(Worker):
                             f"the phase")
                 return
             for slot, lat, res in events:
-                i, fdi, r_off, length, rd, attempts = slot_op.pop(slot)
+                entry = slot_op.pop(slot)
+                i, fdi, r_off, length, rd, attempts, t_sub, op = entry
                 if res < 0:
                     if -res == errno.ETIMEDOUT:
                         # --iotimeout cancelled a hung op (audited; the
                         # error itself is transient, so --ioretries can
                         # re-drive the op on the re-armed slot)
                         self.io_timeouts += 1
-                    retry_or_raise(slot, i, fdi, r_off, length, rd,
-                                   attempts,
+                    retry_or_raise(slot, entry,
                                    OSError(-res, os.strerror(-res)))
                     continue
                 if res != length:
-                    retry_or_raise(slot, i, fdi, r_off, length, rd,
-                                   attempts,
+                    retry_or_raise(slot, entry,
                                    ShortIOError(rd, r_off, res, length))
                     continue
                 lat_arr[i] = lat
+                done[i] = 1
                 state["bytes"] += res
                 ctx.stream_fused_ops += 1
                 if self._tracer is not None:
@@ -1386,7 +1428,7 @@ class LocalWorker(Worker):
                         "read" if rd else "write",
                         phase_name(self.shared.current_phase),
                         self._tracer.now_ns() - int(lat) * 1000, lat,
-                        self.rank, r_off, length, slot=slot)
+                        self.rank, r_off, length, slot=slot, op_id=op)
                 if self._slowops is not None:
                     # per-op latency straight from the engine's reap
                     # event; file attribution via the stripe fd index
@@ -1402,10 +1444,19 @@ class LocalWorker(Worker):
                         start_ns=(time.perf_counter_ns()
                                   - int(lat) * 1000))
                 if rd:
+                    # the op waited from its reap until here, behind the
+                    # earlier ops of the batch (ring only: the span
+                    # encloses their stages)
+                    t_disp = time.perf_counter_ns()
+                    self.reap_queue_usec += (t_disp - t_reaped) // 1000
+                    self.reap_queue_ops += 1
+                    if st is not None:
+                        st.record("reap_queue", "stream", t_reaped, t_disp,
+                                  op)
                     # host->HBM DMA + verify (host memcmp or on-device),
                     # identical to the Python loop's post-read hook
                     self._post_read_actions(self._io_bufs[slot], r_off,
-                                            length)
+                                            length, t_sub, op)
                     if hold:  # frozen per phase, see the caller
                         held.append(slot)
                         while len(held) > hold:
@@ -1447,7 +1498,9 @@ class LocalWorker(Worker):
                     self._pre_write_fill(self._io_bufs[slot], r_off,
                                          length)
                 fdi = int(fd_idx[i]) if fd_idx is not None else 0
-                slot_op[slot] = (i, fdi, r_off, length, rd, 0)
+                op = st.next_op() if st is not None else None
+                slot_op[slot] = (i, fdi, r_off, length, rd, 0,
+                                 time.perf_counter_ns(), op)
                 stream.submit(slot, fdi, r_off, length, is_write=not rd)
                 if self._staging_pool is not None:
                     self._staging_pool.note_occupancy(len(slot_op))
@@ -1455,10 +1508,11 @@ class LocalWorker(Worker):
                 reap_some(1)
         except WorkerInterruptedException:
             _account_chunk(self, lat_arr, lengths_np, n, state["bytes"],
-                           total, flags)
+                           total, flags, done)
             raise
         _account_chunk(self, lat_arr, lengths_np, n, state["bytes"],
                        total, flags)
+        self._book_resident()
 
     #: bounds for one native engine call, so live stats progress and
     #: interrupts stay responsive (shared by every native delegation)
@@ -1665,9 +1719,15 @@ class LocalWorker(Worker):
     # -- read-side block actions --------------------------------------------
 
     def _post_read_actions(self, buf: memoryview, offset: int,
-                           length: int) -> None:
+                           length: int, submit_ns: int = 0,
+                           op=None) -> None:
+        """H2D and verify of a read block. submit_ns: the op's storage
+        submit (perf_counter_ns; 0 books no resident latency); op: its
+        span id."""
         cfg = self.cfg
         if self._tpu is not None:
+            self._tpu.op_submit_ns = submit_ns
+            self._tpu.op_id = op
             # host->HBM DMA of the read block (replaces cudaMemcpy H2D post-
             # read / cuFile read, reference LocalWorker.cpp:2633-2749);
             # guarded for --tpufallback chip failover

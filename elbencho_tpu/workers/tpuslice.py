@@ -200,9 +200,6 @@ class _StreamShardReader:
         if worker.cfg.io_timeout_secs:
             self._stream.set_timeout(
                 worker.cfg.io_timeout_secs * 1_000_000)
-        if worker._tracer is not None:
-            self._stream.tracer = worker._tracer
-            self._stream.trace_rank = worker.rank
         self.backend_name = self._stream.backend_name
         self.pooled = self._stream.pooled
 
@@ -224,9 +221,17 @@ class _StreamShardReader:
                 self._stream.submit(slot, fd_idx, off, length, False)
                 slot_op[slot] = next_op
                 next_op += 1
-            for slot, lat_usec, res in self._stream.reap(
-                    min_complete=1, timeout_msecs=1000,
-                    interrupt_flag=worker._native_interrupt):
+            st = worker._stages
+            token = st.begin("stream_reap") if st is not None else None
+            t0 = time.perf_counter_ns() if st is not None else 0
+            events = self._stream.reap(
+                min_complete=1, timeout_msecs=1000,
+                interrupt_flag=worker._native_interrupt)
+            if st is not None:
+                st.end(token, "stream_reap", "stream", t0,
+                       time.perf_counter_ns(), events=len(events),
+                       min_complete=1)
+            for slot, lat_usec, res in events:
                 op_idx = slot_op.pop(slot)
                 fd_idx, off, length = ops[op_idx]
                 if res != length:
@@ -413,9 +418,7 @@ def _run_slice_phase_guarded(worker, state, devices, is_driver,
                 max(len(worker._staging_pool.views), 1))
     pipeline = TransferPipeline(depth,
                                 budget_usec=cfg.tpu_dispatch_budget_usec)
-    if worker._tracer is not None:
-        pipeline.tracer = worker._tracer
-        pipeline.trace_rank = worker.rank
+    pipeline.stages = worker._stages
 
     # storage reader: fused native-stream ring where eligible, else the
     # preadv loop — logged once per phase like the single-chip path

@@ -277,6 +277,33 @@ def test_offpath_guards_fixture(tmp_path, capsys):
     assert err.count(": off-path-guards:") == 1  # the guarded forms pass
 
 
+def test_offpath_guards_patrol_the_stage_span_handles(tmp_path, capsys):
+    """The per-phase stage-span handles (worker ``_stages``, context and
+    pipeline ``stages``) are patrolled like the tracer."""
+    root = write_tree(tmp_path, {
+        "elbencho_tpu/__init__.py": "",
+        "elbencho_tpu/tpu/device.py": (
+            "class Pipe:\n"
+            "    def drain(self):\n"
+            "        self.stages.record('h2d_wait')\n"       # unguarded
+            "    def submit(self):\n"
+            "        st = self.stages\n"
+            "        tok = st.begin('x') if st is not None else None\n"
+            "        if st is not None:\n"
+            "            st.end(tok, 'x')\n"),
+        "elbencho_tpu/workers/local_worker.py": (
+            "class Worker:\n"
+            "    def loop(self):\n"
+            "        return self._stages.next_op()\n"),    # unguarded
+    })
+    rc, _out, err = run_cli(
+        ["--root", root, "--rule", "off-path-guards"], capsys)
+    assert rc == 1
+    assert "elbencho_tpu/tpu/device.py:3: off-path-guards:" in err
+    assert "elbencho_tpu/workers/local_worker.py:3: off-path-guards:" in err
+    assert err.count(": off-path-guards:") == 2
+
+
 # --- rule: wire-hygiene -----------------------------------------------------
 
 WIRE_FIXTURE = {

@@ -168,6 +168,36 @@ def test_telemetry_off_path_is_noop(tmp_path, monkeypatch):
     assert coord.statistics.telemetry is None
 
 
+def test_stage_spans_off_path_creates_no_annotation(tmp_path, monkeypatch):
+    """A TPU read phase without --tracefile and without a recording
+    profiler arms no stage-span handle and opens no profiler annotation:
+    every stage hook stays one `is None` test, while the always-on stage
+    counters still book."""
+    import json
+    import jax.profiler
+    from elbencho_tpu.telemetry.tracer import StageSpans
+
+    def boom(*_a, **_k):
+        raise AssertionError("stage spans touched with tracing off")
+
+    class NoAnnotation(jax.profiler.TraceAnnotation):
+        def __init__(self, *_a, **_k):
+            boom()
+
+    monkeypatch.setattr(StageSpans, "__init__", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", NoAnnotation)
+    from elbencho_tpu.cli import main
+    data = tmp_path / "data.bin"
+    data.write_bytes(os.urandom(256 * 1024))
+    jf = tmp_path / "r.json"
+    assert main(["-r", "-t", "1", "-b", "64K", "--iodepth", "2",
+                 "--tpuids", "0", "--nolive", "--jsonfile", str(jf),
+                 str(data)]) == 0
+    rec = json.loads(jf.read_text().splitlines()[-1])
+    assert rec["TpuReadyWaitOps"] == 4
+    assert rec["OpResidentLatHisto"]["LatNumValues"] == 4
+
+
 # ---------------------------------------------------------------------------
 # local scrape + trace with the TPU data path (dispatch/DMA sub-spans)
 # ---------------------------------------------------------------------------
@@ -286,6 +316,32 @@ def test_master_fleet_metrics_and_trace_under_load(tpu_services, tmp_path):
         f"127.0.0.1:{p}" for p in ports)
     assert write_rec["TelemetryScrapes"] > 0
     assert write_rec["TraceEvents"] >= 2
+
+
+def test_service_record_carries_the_stage_counters(tpu_services, tmp_path):
+    """The stage counters travel the service wire like TpuDispatchUSec:
+    the master's record sums each host's USec and Ops keys, and merges
+    the hosts' op-resident histograms."""
+    ports = [p.args[p.args.index("--port") + 1] for p in tpu_services]
+    hosts = ",".join(f"127.0.0.1:{p}" for p in ports)
+    bench = tmp_path / "bench"
+    bench.mkdir()
+    jsonfile = tmp_path / "out.json"
+    from elbencho_tpu.cli import main
+    common = ["-d", "-t", "1", "-n", "1", "-N", "2", "-s", "128K", "-b",
+              "32K", "--hosts", hosts, "--tpuids", "0", "--verify", "7",
+              "--nolive"]
+    assert main(["-w", *common, str(bench)]) == 0
+    assert main(["-r", *common, "--tpuverify", "--jsonfile", str(jsonfile),
+                 str(bench)]) == 0
+    rec = next(json.loads(ln) for ln in jsonfile.read_text().splitlines()
+               if json.loads(ln)["Phase"] == "READ")
+    ops = rec["TpuH2dStagedOps"] + rec["TpuH2dDirectOps"]
+    assert ops == 2 * 2 * 4  # two hosts, two files of four blocks each
+    assert rec["TpuReadyWaitOps"] == ops
+    assert rec["TpuVerifyOps"] == ops
+    assert rec["TpuVerifySyncUSec"] > 0 and rec["TpuReadyWaitUSec"] >= 0
+    assert rec["OpResidentLatHisto"]["LatNumValues"] == ops
 
 
 def test_service_metrics_route_idle(tpu_services):
