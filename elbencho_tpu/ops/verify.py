@@ -4,7 +4,8 @@ The host-side verify (LocalWorker::postReadIntegrityCheckVerifyBuf,
 LocalWorker.cpp:2170) compares every 64-bit word against ``offset + salt``.
 On TPU we verify blocks already resident in HBM without a device->host
 round-trip: a Pallas kernel reduces the block to (sum, xor) fingerprints,
-compared against closed-form expected values computed on the host.
+compared against expected values the host computes in closed form, with
+integer arithmetic whose cost does not grow with the block.
 Fingerprint math is mod 2^32 (TPU-native word size).
 """
 
@@ -14,7 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -25,21 +25,38 @@ _SUBLANES = 8    # int32 sublanes per vreg: the (8, 128) accumulator tile
 _TILE_ROWS = 1024
 
 
+def _xor_upto(m: int) -> int:
+    """xor of 0..m (0 for m < 0), by the period-4 identity."""
+    return (m, 1, m + 1, 0)[m & 3] if m >= 0 else 0
+
+
 def expected_fingerprint_host(file_offset: int, length: int,
                               salt: int) -> "tuple[int, int]":
-    """Closed-form (sum mod 2^32, xor) of the uint32-word view of the
-    verify pattern for [file_offset, file_offset+length)."""
-    n_words64 = length // 8
-    i = np.arange(n_words64, dtype=np.uint64)
-    with np.errstate(over="ignore"):
-        vals = np.uint64(file_offset) + np.uint64(salt) + i * np.uint64(8)
-    lo = (vals & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    hi = (vals >> np.uint64(32)).astype(np.uint32)
-    s = (int(lo.sum(dtype=np.uint64)) + int(hi.sum(dtype=np.uint64))) \
-        & 0xFFFFFFFF
-    x = int(np.bitwise_xor.reduce(lo) ^ np.bitwise_xor.reduce(hi)) \
-        if n_words64 else 0
-    return s, x
+    """(sum mod 2^32, xor) of the uint32-word view of the verify pattern
+    for [file_offset, file_offset+length), in closed form.
+
+    Word i is ``v = (file_offset + salt + 8*i) mod 2^64``, seen as its low
+    and high uint32 halves. Between two wraps of the low half the high
+    word is constant and the low words are ``8*(q+j) + r``: the sum is an
+    arithmetic series plus ``k*hi``, the xor that of the run ``q..q+k-1``
+    shifted left 3, with ``r`` and ``hi`` when ``k`` is odd. One segment
+    per 2^32 wrap of the low half, so O(1 + length / 2^32). A wrap at
+    2^64 is a wrap of the high word mod 2^32. Tail bytes past the last
+    whole word are not part of the fingerprint."""
+    n = length // 8
+    v = (file_offset + salt) & 0xFFFFFFFFFFFFFFFF
+    s = x = 0
+    while n:
+        lo, hi = v & 0xFFFFFFFF, (v >> 32) & 0xFFFFFFFF
+        q, r = lo >> 3, lo & 7
+        k = min(n, (1 << 29) - q)  # words before the low half wraps
+        s += k * (lo + hi) + 4 * k * (k - 1)
+        x ^= (_xor_upto(q + k - 1) ^ _xor_upto(q - 1)) << 3
+        if k & 1:
+            x ^= r ^ hi
+        v += 8 * k
+        n -= k
+    return s & 0xFFFFFFFF, x
 
 
 def _fingerprint_kernel(x_ref, sum_ref, xor_ref):

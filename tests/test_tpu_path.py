@@ -571,6 +571,76 @@ def test_fingerprint_kernel_interpret_matches_closed_form(length):
     assert (int(s) & 0xFFFFFFFF, int(x) & 0xFFFFFFFF) == want
 
 
+def _numpy_fingerprint_oracle(file_offset, length, salt):
+    """The expected fingerprint word by word in numpy: the pattern's
+    uint64 words split into uint32 halves, summed mod 2^32 and xored."""
+    n_words64 = length // 8
+    i = np.arange(n_words64, dtype=np.uint64)
+    with np.errstate(over="ignore"):
+        vals = np.uint64(file_offset) + np.uint64(salt) + i * np.uint64(8)
+    lo = (vals & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    hi = (vals >> np.uint64(32)).astype(np.uint32)
+    s = (int(lo.sum(dtype=np.uint64)) + int(hi.sum(dtype=np.uint64))) \
+        & 0xFFFFFFFF
+    x = int(np.bitwise_xor.reduce(lo) ^ np.bitwise_xor.reduce(hi)) \
+        if n_words64 else 0
+    return s, x
+
+
+def _random_fingerprint_cases(n=200, seed=24):
+    """(offset, length <= 64 KiB, salt) triples; half of them start just
+    under a low-word wrap, so the block crosses it."""
+    import random
+    rng = random.Random(seed)
+    cases = []
+    for _ in range(n):
+        salt = rng.getrandbits(64)
+        if rng.random() < 0.5:
+            offset = ((rng.getrandbits(32) << 32) - salt
+                      - rng.randrange(1 << 16)) % (1 << 64)
+        else:
+            offset = rng.getrandbits(64)
+        cases.append((offset, rng.randrange((64 << 10) + 1), salt))
+    return cases
+
+
+_U64 = 1 << 64
+
+
+@pytest.mark.parametrize("cases", [
+    [(0, 0, 5)], [(8, 4, 5)], [(4096, 8, 0x5A5A5A5A12345)],
+    [(81920, 12, 42)], [(7 * 4096, 4096, 99)],
+    [(7 * 4096, (1 << 20) + 24, 0x5A5A5A5A12345)],
+    [(1 << 30, 16 << 20, 7)],
+    [((5 << 32) - 24 - 1000, 4096, 1000)],      # low-word wrap inside
+    [(_U64 - 2048 - 3, 4096 + 8 + 5, 3)],       # wrap at 2^64
+    [(12345, 8000 + 5, 0x9E3779B97F4A7C15)],    # odd salt, unaligned
+    [((1 << 32) - (8 << 20), 16 << 20, 0)],     # 16 MiB across 2^32
+    _random_fingerprint_cases(),
+], ids=["len0", "len4", "len8", "len12", "len4096", "len1m24", "len16m",
+        "lowwrap", "wrap2p64", "oddsalt_unaligned", "16m_across_2p32",
+        "random200"])
+def test_expected_fingerprint_matches_numpy_oracle(cases):
+    """The closed form equals the word-by-word fingerprint bit for bit."""
+    for offset, length, salt in cases:
+        assert expected_fingerprint_host(offset, length, salt) == \
+            _numpy_fingerprint_oracle(offset, length, salt), \
+            (offset, length, salt)
+
+
+@pytest.mark.parametrize("split", [8, (3 << 32) + 8 * 12345, (1 << 40) - 8])
+def test_expected_fingerprint_folds_over_a_split(split):
+    """The pair for [a, a+L) is the pair for [a, a+m) folded with the pair
+    for [a+m, a+L): 1 TiB across several 2^32 wraps of the low word and
+    one of the whole word, beyond what any word-by-word oracle could
+    check."""
+    a, length, salt = 12345 * 8, 1 << 40, _U64 - (1 << 38) + 0x9E37
+    s1, x1 = expected_fingerprint_host(a, split, salt)
+    s2, x2 = expected_fingerprint_host(a + split, length - split, salt)
+    assert expected_fingerprint_host(a, length, salt) == \
+        ((s1 + s2) & 0xFFFFFFFF, x1 ^ x2)
+
+
 def test_pallas_lowering_error_raises_instead_of_falling_back(monkeypatch):
     """A kernel that cannot lower (here: compiled, not interpreted, on
     the CPU) fails the verify; nothing stands in for the kernel."""
