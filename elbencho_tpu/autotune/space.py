@@ -3,7 +3,8 @@
 Each axis maps one CLI flag onto a geometric value ladder plus the
 config-validation constraints that flag already enforces at
 ``BenchConfig.check`` time — the tuner must never propose a point the
-CLI would reject (``--tpudepth`` > ``--iodepth`` under ``--tpudirect``,
+CLI would reject or clamp (``--tpudepth`` > ``--iodepth`` for an
+unbatched transfer,
 ``--tpubatch`` > 1 next to ``--tpuverify``, a poll interval at/above
 the ``--svcleasesecs`` lease, ...). The space is derived from the
 EFFECTIVE config, so axes that cannot apply to this run (TPU knobs
@@ -122,6 +123,19 @@ class KnobSpace:
 
     # -- constraint validation ----------------------------------------------
 
+    def _tpudepth_clamped(self, values: "dict[str, int]") -> str:
+        """Why the worker clamps --tpudepth to --iodepth at this point
+        ("" = it does not): every unbatched transfer reads its host
+        buffer until the ring drains it (LocalWorker's TPU prep)."""
+        cfg = self._cfg
+        if cfg.use_tpu_direct:
+            return "under --tpudirect"
+        batch = values.get("tpubatch", int(getattr(cfg, "tpu_batch_blocks",
+                                                   1)))
+        if cfg.do_tpu_verify or batch <= 1:
+            return "for an unbatched staged transfer"
+        return ""
+
     def invalid_reason(self, values: "dict[str, int]", name: str,
                        candidate: int) -> "str | None":
         """Why ``candidate`` on axis ``name`` cannot combine with the
@@ -134,13 +148,13 @@ class KnobSpace:
             if candidate <= cfg.num_rwmix_read_threads:
                 return ("--rwmixthr must stay below --threads "
                         "(needs at least one writer)")
-        if name == "tpudepth" and cfg.use_tpu_direct:
+        clamped = self._tpudepth_clamped(values)
+        if name == "tpudepth" and clamped:
             iodepth = values.get(
                 "iodepth", int(getattr(cfg, "io_depth", 1)))
             if candidate > iodepth:
-                return ("--tpudepth is clamped to --iodepth under "
-                        "--tpudirect")
-        if name == "iodepth" and cfg.use_tpu_direct:
+                return f"--tpudepth is clamped to --iodepth {clamped}"
+        if name == "iodepth" and clamped:
             # partial value maps (sweep grids) fall back to the PINNED
             # config value, not 0 — a pinned --tpudepth must clamp a
             # swept iodepth exactly like a swept tpudepth would
@@ -148,7 +162,15 @@ class KnobSpace:
                                   int(getattr(cfg, "tpu_depth", 0)))
             if tpudepth and candidate < tpudepth:
                 return ("--iodepth below the current --tpudepth would "
-                        "silently re-clamp the ring under --tpudirect")
+                        f"silently re-clamp the ring {clamped}")
+        if name == "tpubatch" and candidate <= 1 \
+                and self._tpudepth_clamped({**values, "tpubatch": candidate}):
+            tpudepth = values.get("tpudepth",
+                                  int(getattr(cfg, "tpu_depth", 0)))
+            if tpudepth > values.get("iodepth",
+                                     int(getattr(cfg, "io_depth", 1))):
+                return ("--tpubatch 1 would silently clamp the current "
+                        "--tpudepth to --iodepth")
         if name == "svcupint":
             lease_ms = cfg.svc_lease_secs * 1000
             if lease_ms and candidate >= lease_ms:

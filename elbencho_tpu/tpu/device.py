@@ -100,7 +100,9 @@ PATH_AUDIT_COUNTERS = (
     # within one record: the time the host blocked in the transfer
     # ring's completion wait; the on-device verify's sync (transfer +
     # kernel) and the host's expected fingerprint; and, worker-owned,
-    # the wait of a reaped op behind the earlier ops of its reap batch
+    # the wait of a reaped op behind the earlier ops of its reap batch,
+    # and the fused ring's wait in release drains (drains made only to
+    # free a held-back slot) over the held slots it let go
     ("pipe_ready_wait_usec", "TpuReadyWaitUSec", "tpu_ready_wait_usec"),
     ("pipe_ready_wait_ops", "TpuReadyWaitOps", "tpu_ready_wait_ops"),
     ("verify_sync_usec", "TpuVerifySyncUSec", "tpu_verify_sync_usec"),
@@ -108,6 +110,9 @@ PATH_AUDIT_COUNTERS = (
     ("verify_expect_usec", "TpuVerifyExpectUSec", "tpu_verify_expect_usec"),
     ("reap_queue_usec", "ReapQueueUSec", "reap_queue_usec"),
     ("reap_queue_ops", "ReapQueueOps", "reap_queue_ops"),
+    ("slot_release_wait_usec", "SlotReleaseWaitUSec",
+     "slot_release_wait_usec"),
+    ("slot_release_wait_ops", "SlotReleaseWaitOps", "slot_release_wait_ops"),
 )
 
 #: counters owned by the Worker object itself rather than the
@@ -120,7 +125,7 @@ PATH_AUDIT_WORKER_ATTRS = frozenset({
     "pool_sqpoll_ops", "shard_ingest_mib", "ici_redist_mib",
     "ici_redist_usec", "ici_gbps_hwm", "slow_ops_recorded",
     "op_samples_dropped", "tail_p999_usec_hwm", "reap_queue_usec",
-    "reap_queue_ops"})
+    "reap_queue_ops", "slot_release_wait_usec", "slot_release_wait_ops"})
 
 #: counters owned by the worker's StagingPool: the merge reads them
 #: from worker._staging_pool when one is attached (local workers), and
@@ -736,18 +741,23 @@ class TpuWorkerContext:
         Two transfer paths (the cuFileRead-vs-cudaMemcpy split of the
         reference, LocalWorker.cpp:2633-2749):
 
-        - staged (default): ``jax.device_put`` of the buffer view. jax's
-          host-buffer semantics defensively guarantee the source can be
-          reused the moment the call returns, which costs an internal
-          staging copy of every block.
+        - staged (default): ``jax.device_put`` of the buffer view. The
+          call returns before the transfer has read the buffer: on a TPU
+          the runtime reads the host buffer until the transfer completes
+          (a 16 MiB read at --iodepth 8 landed wrong bytes in HBM when
+          the buffer was rewritten at once). Only the transfer's
+          completion releases the buffer.
         - direct (--tpudirect): the page-aligned I/O buffer (mmap-backed,
           64B-aligned for O_DIRECT) is exported via dlpack straight into
-          the device transfer — no defensive copy; on host-backed devices
-          (virtual CPU mesh) the import is true zero-copy. The stability
-          guarantee dlpack shifts to the producer is exactly what the
-          drain-to-depth-1 ring below provides: a host buffer is never
-          rewritten before its transfer completed (CuFileHandleData
-          register-once discipline, reference CuFileHandleData.h:18-73).
+          the device transfer; on host-backed devices (virtual CPU mesh)
+          the import is true zero-copy, and the array aliases the buffer
+          until its transfer drains.
+
+        So on both paths a host buffer must not be rewritten before its
+        transfer completed (CuFileHandleData register-once discipline,
+        reference CuFileHandleData.h:18-73): the drain-to-depth-1 ring
+        below provides that to callers that rotate depth buffers, and
+        the fused stream loop holds slots back (holdback_depth).
         """
         n_words = length // 4
         np_view = np.frombuffer(buf[:n_words * 4], dtype=np.uint32)
@@ -864,9 +874,17 @@ class TpuWorkerContext:
     def _staged_submit(self, np_view: np.ndarray):
         """device_put of the block, then — when a drained staging slot of
         matching shape exists — a donation-based jitted device copy into
-        it, so the slot's HBM buffer is reused instead of re-allocated
-        per block (the allocGPUIOBuffer-once discipline of the reference,
-        LocalWorker.cpp:1427: buffers live for the worker's lifetime).
+        it (``staged_slot_copy``), so the slot's HBM buffer is reused
+        instead of re-allocated per block (the allocGPUIOBuffer-once
+        discipline of the reference, LocalWorker.cpp:1427: buffers live
+        for the worker's lifetime).
+
+        The device_put is asynchronous: it may still read ``np_view``
+        after this returns. The array returned (the copy's output, which
+        waits on the transfer) is ready only once the host buffer has
+        been read, so the caller may rewrite the buffer after the ring
+        drained this entry, not before.
+
         The slot rotation mirrors the ring: a slot is reused exactly
         depth staged SUBMITS later (a dedicated counter — pipeline.ops
         also counts D2H note_dispatch entries, so keying on it would
@@ -900,9 +918,7 @@ class TpuWorkerContext:
         the copy step off there rather than paying a copy for nothing."""
         jax = _get_jax()
         if self._copy_step is None:
-            self._copy_step = jax.jit(
-                lambda d, s: jax.lax.dynamic_update_slice(d, s, (0,)),
-                donate_argnums=(0,))
+            self._copy_step = jax.jit(staged_slot_copy, donate_argnums=(0,))
         if not self._donate_probed:
             self._donate_probed = True
             import warnings
@@ -964,15 +980,16 @@ class TpuWorkerContext:
 
     def holdback_depth(self) -> int:
         """How many freshly-ingested staging slots the fused stream loop
-        must keep OUT of the engine's ring after their host_to_device:
-        with an unbatched --tpudirect import the device array aliases
-        the host buffer until its transfer drains, and the pipeline
-        holds at most depth-1 transfers after every submit — so holding
-        the last depth-1 ingested slots is exactly the drain guarantee
-        the dlpack stability contract needs. The staged path (and the
-        --tpubatch aggregation path) copy the buffer out at submit time
-        and need no holdback."""
-        if self.direct and self._h2d_direct_ok and self.batch_blocks == 1:
+        must keep OUT of the engine's ring after their host_to_device.
+        An unbatched transfer reads the host buffer until it completes,
+        on both paths (host_to_device): a --tpudirect import aliases it,
+        and a staged device_put reads it after the call returned. The
+        pipeline holds at most depth-1 transfers after every submit, so
+        holding the last depth-1 ingested slots is exactly the drain
+        guarantee both need; 0 at depth 1, where every submit drains.
+        The --tpubatch path copies each block into its own aggregation
+        ring at host_to_device and needs no holdback."""
+        if self.batch_blocks == 1:
             return max(self.pipeline_depth - 1, 0)
         return 0
 
@@ -1222,6 +1239,13 @@ def fold_fingerprint(holder, block_sum: int, block_xor: int) -> None:
     holder.tpu_fp_sum = (holder.tpu_fp_sum + block_sum) & 0xFFFFFFFF
     holder.tpu_fp_xor ^= block_xor
     holder.tpu_fp_blocks += 1
+
+
+def staged_slot_copy(dst, src):
+    """The staged path's per-block device program: the block just put on
+    the device copied into a recycled staging slot (dst, donated). Its
+    program is ``jit_staged_slot_copy`` in a device trace."""
+    return _get_jax().lax.dynamic_update_slice(dst, src, (0,))
 
 
 def _d2h_async(arr) -> None:

@@ -122,9 +122,13 @@ class Worker:
         self.tail_p999_usec_hwm = 0
         # per-op stage counters of the storage->HBM path (worker-owned
         # PATH_AUDIT_COUNTERS entries): a reaped op's wait behind the
-        # earlier ops of its reap batch, and the ops that waited
+        # earlier ops of its reap batch, and the ops that waited; the
+        # fused ring's wait in release drains, and the held-back slots
+        # it let go
         self.reap_queue_usec = 0
         self.reap_queue_ops = 0
+        self.slot_release_wait_usec = 0
+        self.slot_release_wait_ops = 0
         # storage submit -> block resident in HBM, per H2D op of the
         # block loops (JSON OpResidentLatHisto)
         self.op_resident_histo = LatencyHistogram()
@@ -185,6 +189,8 @@ class Worker:
         self.tail_p999_usec_hwm = 0
         self.reap_queue_usec = 0
         self.reap_queue_ops = 0
+        self.slot_release_wait_usec = 0
+        self.slot_release_wait_ops = 0
         self.op_resident_histo.reset()
         if self._slowops is not None:
             self._slowops.reset_phase()

@@ -148,20 +148,25 @@ class LocalWorker(Worker):
                                       tpu_devices)
             chip = cfg.tpu_ids[self.rank % len(cfg.tpu_ids)]
             # --tpudepth overrides the iodepth ride-along (the
-            # reference's cuFile iodepth analogue). Under --tpudirect the
-            # depth is clamped to the host-buffer count: an unbatched
-            # direct import aliases its host buffer until the ring drains
-            # it, and buffer rotation only guarantees that when the ring
-            # is no deeper than the rotation period.
+            # reference's cuFile iodepth analogue). For every unbatched
+            # transfer the depth is clamped to the host-buffer count: a
+            # direct import aliases its host buffer and a staged
+            # device_put reads it until the ring drains the transfer,
+            # and buffer rotation only guarantees that when the ring is
+            # no deeper than the rotation period. Only a staged --tpubatch
+            # run is left unclamped: it copies each block into its own
+            # aggregation ring (and --tpuverify turns batching off).
             depth = max(cfg.tpu_depth or cfg.io_depth, 1)
-            if cfg.use_tpu_direct and depth > max(cfg.io_depth, 1):
+            unbatched = (cfg.use_tpu_direct or cfg.do_tpu_verify
+                         or max(cfg.tpu_batch_blocks, 1) == 1)
+            if unbatched and depth > max(cfg.io_depth, 1):
                 if self.rank % max(1, cfg.num_threads) == 0:
                     logger.log(
                         logger.LOG_NORMAL,
                         f"NOTE: --tpudepth {depth} exceeds --iodepth "
                         f"{cfg.io_depth}; clamped to {max(cfg.io_depth, 1)} "
-                        f"under --tpudirect (a host buffer must not be "
-                        f"rewritten before its zero-copy import drained)")
+                        f"(a host buffer must not be rewritten before the "
+                        f"transfer that reads it drained)")
                 depth = max(cfg.io_depth, 1)
             self._tpu = TpuWorkerContext(
                 chip_id=chip, block_size=cfg.block_size,
@@ -1271,12 +1276,12 @@ class LocalWorker(Worker):
             stream.set_fault_from_spec(fault_spec)
         # slot-reuse discipline: a slot is free, in the engine ring
         # (slot_op), or held back after its H2D until the transfer ring
-        # provably drained its zero-copy import (holdback_depth). The
-        # depth is FROZEN for the phase: if the direct path latches off
-        # mid-stream, dropping it live would release slots whose
-        # earlier zero-copy imports are still in the ring undrained —
-        # holding staged-era slots a little longer is merely
-        # conservative, the reverse is a use-after-reuse.
+        # provably drained the transfer that reads it (holdback_depth:
+        # staged and direct alike). The depth is FROZEN for the phase:
+        # a host-staging failover mid-stream must not release slots
+        # whose earlier transfers are still in the ring undrained —
+        # holding them a little longer is merely conservative, the
+        # reverse is a use-after-reuse.
         hold = self._tpu.holdback_depth()
         free = deque(range(len(slot_addrs)))
         held: "deque[int]" = deque()
@@ -1316,8 +1321,11 @@ class LocalWorker(Worker):
 
         Per op, a storage-submit stamp rides in its slot_op entry into
         the transfer ring (the op-resident latency), and the wait from
-        its reap to its H2D dispatch books ReapQueueUSec; with stage
-        spans armed, the op's spans share one id."""
+        its reap to its H2D dispatch books ReapQueueUSec; each held-back
+        slot that goes back to the engine books one SlotReleaseWaitOps,
+        and a release drain (one made only to free a held slot) books
+        its wait in SlotReleaseWaitUSec; with stage spans armed, the op's
+        spans share one id."""
         import ctypes
         from ..utils.native import _account_chunk
         cfg = self.cfg
@@ -1460,7 +1468,10 @@ class LocalWorker(Worker):
                     if hold:  # frozen per phase, see the caller
                         held.append(slot)
                         while len(held) > hold:
+                            # the submit's own drain completed its
+                            # transfer: a release without a wait
                             free.append(held.popleft())
+                            self.slot_release_wait_ops += 1
                     else:
                         free.append(slot)
                 else:
@@ -1479,11 +1490,20 @@ class LocalWorker(Worker):
                         # its H2D from the transfer ring: after
                         # drain_to(len(held)-1) the ring's FIFO in-flight
                         # window only covers the newer held slots, so
-                        # held[0]'s import has provably completed.
+                        # held[0]'s transfer has provably completed.
                         # Without this, the holdback would cap the engine
                         # ring at n_slots-(depth-1) ops and serialize
-                        # storage I/O under --tpudirect.
+                        # storage I/O. The drain encloses its h2d_wait,
+                        # so its span stays in the ring.
+                        t_rel = time.perf_counter_ns()
                         ctx.drain_to(len(held) - 1)
+                        t_freed = time.perf_counter_ns()
+                        self.slot_release_wait_usec += \
+                            (t_freed - t_rel) // 1000
+                        self.slot_release_wait_ops += 1
+                        if st is not None:
+                            st.record("slot_release", "stream", t_rel,
+                                      t_freed, slot=held[0])
                         free.append(held.popleft())
                     else:
                         reap_some(1)

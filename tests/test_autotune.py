@@ -7,7 +7,7 @@ Covers the subsystem at every layer:
 - space: axis applicability follows the effective config (TPU axes need
   a TPU path, control-plane axes need a streamed fleet) and the
   constraint validators mirror config validation (tpudepth<=iodepth
-  under --tpudirect, svcupint below the lease);
+  for an unbatched transfer, svcupint below the lease);
 - config: flag parsing (bare --autotune = 60s), the --autotune-* gate,
   and the scenario/resume/service rejections;
 - profile: emit -> load (-c) -> identical knob values;
@@ -84,7 +84,9 @@ def _space(extra=_ALL_AXES_ARGS):
 def test_each_verdict_moves_the_named_axis(verdict, axis):
     """The doctor's hint table steers the FIRST move: the axis probed
     right after the baseline is the one the verdict names."""
-    space = _space()
+    # a pinned --tpudepth below --iodepth leaves the unbatched ring room
+    # to deepen (it is clamped to --iodepth)
+    space = _space(_ALL_AXES_ARGS + ("--iodepth", "8", "--tpudepth", "2"))
 
     def run_probe(_values):
         return ProbeOutcome(100.0, verdict=verdict)
@@ -237,8 +239,17 @@ def test_tpudirect_clamps_tpudepth_to_iodepth():
     assert space.step(values, "tpudepth", -1) == 2
     # and iodepth may not dive under the current tpudepth either
     assert space.invalid_reason(values, "iodepth", 2) is not None
+    # the staged path is clamped too while unbatched; --tpubatch copies
+    # each block into its own ring, so a batched point may go deeper
     without_direct = _space(("-r", "--tpuids", "0", "--iodepth", "4"))
-    assert without_direct.step(values, "tpudepth", 1) == 8
+    assert without_direct.step(values, "tpudepth", 1) is None
+    assert "unbatched staged" in without_direct.invalid_reason(
+        values, "tpudepth", 8)
+    batched = {**values, "tpubatch": 2}
+    assert without_direct.step(batched, "tpudepth", 1) == 8
+    assert without_direct.invalid_reason(
+        {**batched, "tpudepth": 8}, "tpubatch", 1) is not None
+    assert without_direct.invalid_reason(batched, "tpubatch", 1) is None
     # partial value maps (sweep grids sweep only SOME axes): the PINNED
     # --tpudepth clamps a swept iodepth even with no tpudepth entry
     pinned = _space(("-r", "--tpuids", "0", "--tpudirect",

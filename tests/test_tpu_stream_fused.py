@@ -234,6 +234,169 @@ def test_fused_direct_mode_parity_and_holdback(tmp_path, monkeypatch):
     assert rec["TpuH2dDirectFallbacks"] == 0
 
 
+class _DeferredPut:
+    """What ``jax.device_put`` of a host buffer may give on a TPU: the
+    transfer reads the buffer only when it is waited on (PjRt reads it
+    until the transfer completes). It has no device buffer to donate, so
+    the staged path's slot copy latches off and each ring entry is the
+    put itself."""
+
+    def __init__(self, view, device, put):
+        self._view, self._device, self._put = view, device, put
+        self.shape, self.dtype = view.shape, view.dtype
+        self.value = None
+
+    def is_ready(self):
+        return self.value is not None
+
+    def block_until_ready(self):
+        if self.value is None:
+            self.value = self._put(np.array(self._view), self._device)
+            self._view = None  # the transfer is done with the buffer
+        return self
+
+
+def _deferred_device_put(monkeypatch):
+    import jax
+    put = jax.device_put
+
+    def device_put(x, device=None, *args, **kwargs):
+        if isinstance(x, np.ndarray):
+            return _DeferredPut(x, device, put)
+        return put(x, device, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", device_put)
+
+
+def _record_landed(monkeypatch):
+    """(file offset, length, the array the context holds as landed) of
+    every host_to_device call."""
+    from elbencho_tpu.tpu.device import TpuWorkerContext
+    landed = []
+    orig = TpuWorkerContext.host_to_device
+
+    def host_to_device(self, buf, length, verify_salt=0, file_offset=0):
+        orig(self, buf, length, verify_salt=verify_salt,
+             file_offset=file_offset)
+        landed.append((file_offset, length, self._last_ingested))
+
+    monkeypatch.setattr(TpuWorkerContext, "host_to_device", host_to_device)
+    return landed
+
+
+def _staged_read_lands_the_bytes_it_read(tmp_path, monkeypatch, flags,
+                                         fused_ops):
+    """Write 64 KiB blocks of the verify pattern, read them back at
+    --iodepth 8 on the staged path with a deferred-read device_put, and
+    compare every landed array with the pattern at its offset."""
+    _native_stream_or_skip(monkeypatch)
+    target = tmp_path / "f"
+    jf = tmp_path / "res.json"
+    salt = 7
+    rc, _ = _run(["-w", "-t", "1", "-s", "4M", "-b", "64K", "--verify",
+                  salt, "--nolive", target], jf)
+    assert rc == 0
+    _deferred_device_put(monkeypatch)
+    landed = _record_landed(monkeypatch)
+    rc, recs = _run(["-r", "-t", "1", "-s", "4M", "-b", "64K", "--iodepth",
+                     "8", "--tpuids", "0", "--nolive", target] + flags, jf)
+    assert rc == 0
+    rec = _phase_rec(recs, "READ")
+    assert rec["TpuStreamFusedOps"] == fused_ops
+    assert rec["TpuH2dStagedOps"] == 64 and rec["TpuH2dDirectOps"] == 0
+    assert len(landed) == 64
+    wrong = []
+    for offset, length, arr in landed:
+        assert isinstance(arr, _DeferredPut) and arr.value is not None, \
+            f"block at {offset} was never waited on"
+        words = np.asarray(arr.value).view(np.uint64)
+        want = (np.arange(length // 8, dtype=np.uint64) * np.uint64(8)
+                + np.uint64(offset + salt))
+        if not (words == want).all():
+            wrong.append(offset)
+    assert not wrong, f"{len(wrong)} of 64 blocks landed other bytes"
+
+
+def test_fused_staged_depth8_lands_the_bytes_it_read(tmp_path, monkeypatch):
+    """The README's deployment at CPU size: 64 KiB blocks, --iodepth 8,
+    the default (staged) H2D path on the fused ring, with a device_put
+    that reads the host buffer only when its transfer is waited on. No
+    slot may go back to the engine before the transfer that reads it is
+    done, so every landed block is the verify pattern at its offset."""
+    _staged_read_lands_the_bytes_it_read(tmp_path, monkeypatch, [], 64)
+
+
+def test_python_loop_staged_tpudepth_above_iodepth_lands_the_bytes_it_read(
+        tmp_path, monkeypatch):
+    """The Python block loop rotates --iodepth host buffers. With
+    --tpudepth 16 above --iodepth 8, a transfer ring deeper than that
+    rotation would let a staged device_put still read a buffer that
+    storage already rewrote; the depth is clamped to --iodepth for every
+    unbatched transfer, staged as well as direct."""
+    _staged_read_lands_the_bytes_it_read(
+        tmp_path, monkeypatch, ["--tpudepth", "16", "--tpustream", "off"], 0)
+
+
+@pytest.mark.parametrize("direct,depth,batch,want", [
+    (False, 8, 1, 7), (True, 8, 1, 7), (False, 1, 1, 0), (True, 1, 1, 0),
+    (False, 8, 2, 0)], ids=["staged8", "direct8", "staged1", "direct1",
+                            "batch8"])
+def test_holdback_is_depth_less_one_for_every_unbatched_path(
+        direct, depth, batch, want):
+    """The fused ring's slot-release rule does not depend on the path: a
+    slot is held until the transfer that reads it drained."""
+    from elbencho_tpu.tpu.device import TpuWorkerContext
+    ctx = TpuWorkerContext(chip_id=0, block_size=64 << 10, direct=direct,
+                           pipeline_depth=depth, batch_blocks=batch)
+    try:
+        assert ctx.pipeline_depth == depth
+        assert ctx.holdback_depth() == want
+    finally:
+        ctx.close()
+
+
+@pytest.mark.parametrize("slow_storage", [False, True],
+                         ids=["fast", "slow_storage"])
+def test_fused_staged_books_its_slot_releases(tmp_path, monkeypatch,
+                                              slow_storage):
+    """Every held slot that goes back to the engine books one
+    SlotReleaseWaitOps: the phase's ops less the depth-1 still held at
+    its end. With storage slower than the transfer (a non-blocking reap
+    finds nothing) slots go back through release drains, each of which
+    books its wait in SlotReleaseWaitUSec and one ring span
+    ``slot_release``."""
+    _native_stream_or_skip(monkeypatch)
+    from elbencho_tpu.utils.native import NativeStream
+    target = tmp_path / "f"
+    jf = tmp_path / "res.json"
+    trace = tmp_path / "t.json"
+    rc, _ = _run(["-w", "-t", "1", "-s", "1M", "-b", "64K", "--nolive",
+                  target], jf)
+    assert rc == 0
+    orig = NativeStream.reap
+
+    def slow_storage_reap(self, min_complete=1, *args, **kwargs):
+        return [] if min_complete == 0 else \
+            orig(self, min_complete, *args, **kwargs)
+
+    if slow_storage:
+        monkeypatch.setattr(NativeStream, "reap", slow_storage_reap)
+    rc, recs = _run(["-r", "-t", "1", "-s", "1M", "-b", "64K", "--iodepth",
+                     "8", "--tpuids", "0", "--tracefile", trace, "--nolive",
+                     target], jf)
+    assert rc == 0
+    rec = _phase_rec(recs, "READ")
+    assert rec["TpuStreamFusedOps"] == rec["TpuH2dStagedOps"] == 16
+    assert rec["SlotReleaseWaitOps"] == 16 - 7
+    with open(trace) as f:
+        spans = [e for e in json.load(f)["traceEvents"]
+                 if e["name"] == "slot_release"]
+    assert len(spans) <= rec["SlotReleaseWaitOps"]
+    assert rec["SlotReleaseWaitUSec"] == sum(e["dur"] for e in spans)
+    if slow_storage:
+        assert spans
+
+
 def test_fused_skips_tiny_dir_mode_files(tmp_path, monkeypatch):
     """Dir-mode LOSF with files only a couple ring-fills long falls back
     to the Python loop (per-file ring setup would dominate), logged as
