@@ -15,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -81,10 +82,11 @@ def _fingerprint_kernel(x_ref, sum_ref, xor_ref):
 
 @functools.lru_cache(maxsize=None)
 def fingerprint_fn(num_words: int, interpret: bool):
-    """Jitted uint32[num_words] -> (sum, xor) int32 scalars through the
-    Pallas kernel. The block is zero-padded to whole tiles where needed:
-    zeros change neither the sum nor the xor. ``interpret`` runs the
-    kernel in the Pallas interpreter (CPU backends only)."""
+    """Jitted uint32[num_words] -> int32[2] (sum, xor) through the Pallas
+    kernel: one array, so reading a block's result back is one host
+    read. The block is zero-padded to whole tiles where needed: zeros
+    change neither the sum nor the xor. ``interpret`` runs the kernel in
+    the Pallas interpreter (CPU backends only)."""
     rows = -(-max(num_words, 1) // _LANES)
     # a power of two of vregs per tile, for the kernel's halving xor fold
     tile_rows = min(_TILE_ROWS,
@@ -111,20 +113,56 @@ def fingerprint_fn(num_words: int, interpret: bool):
         if pad:
             x = jnp.pad(x, (0, pad))
         s, xr = call(x.reshape(rows, _LANES))
-        return (jnp.sum(s),
-                jax.lax.reduce(xr, jnp.int32(0), jax.lax.bitwise_xor,
-                               (0, 1)))
+        return jnp.stack([jnp.sum(s),
+                          jax.lax.reduce(xr, jnp.int32(0),
+                                         jax.lax.bitwise_xor, (0, 1))])
 
     return run
 
 
-def fingerprint_block_pallas(block_u32) -> "tuple[int, int]":
-    """(sum mod 2^32, xor) of an HBM-resident uint32 block via the Pallas
-    kernel. Interpret mode is chosen from the block's platform (CPU
-    only); on a TPU a lowering or runtime error propagates."""
+class PendingFingerprint:
+    """A block's fingerprint whose kernel was dispatched and not read back
+    yet. ``result()`` reads it (one device->host read) and keeps the
+    (sum mod 2^32, xor) pair; ``==`` / ``!=`` against a pair resolve it."""
+
+    __slots__ = ("array", "_value")
+
+    def __init__(self, array):
+        self.array = array  # int32[2] on the block's device
+        self._value = None
+
+    def result(self) -> "tuple[int, int]":
+        if self._value is None:
+            s, x = np.asarray(self.array).tolist()
+            self._value = (s & 0xFFFFFFFF, x & 0xFFFFFFFF)
+            self.array = None
+        return self._value
+
+    def __eq__(self, other):
+        return self.result() == other
+
+    def __ne__(self, other):
+        return self.result() != other
+
+    __hash__ = None
+
+
+def fingerprint_block_pallas(block_u32) -> PendingFingerprint:
+    """The fingerprint of an HBM-resident uint32 block via the Pallas
+    kernel, dispatched without a host sync: the caller reads it with
+    ``result()`` when it needs the pair. Interpret mode is chosen from
+    the block's platform (CPU only); on a TPU a lowering error
+    propagates here, a runtime error from ``result()``."""
     platform = next(iter(block_u32.devices())).platform
-    s, x = fingerprint_fn(int(block_u32.size), platform == "cpu")(block_u32)
-    return int(s) & 0xFFFFFFFF, int(x) & 0xFFFFFFFF
+    return PendingFingerprint(
+        fingerprint_fn(int(block_u32.size), platform == "cpu")(block_u32))
+
+
+def fingerprint_host(words_u32: np.ndarray) -> "tuple[int, int]":
+    """(sum mod 2^32, xor) of a host uint32 array: the kernel's result for
+    the same words, for a block whose device is gone (chip failover)."""
+    return (int(words_u32.sum(dtype=np.uint64)) & 0xFFFFFFFF,
+            int(np.bitwise_xor.reduce(words_u32)))
 
 
 def check_fingerprint(got: "tuple[int, int]", want: "tuple[int, int]",
@@ -143,7 +181,7 @@ def verify_block_on_device(block_u32, file_offset: int, length: int,
     """The block's (sum, xor) fingerprint; raises ValueError if the
     HBM-resident block does not match the verify pattern for its file
     offset."""
-    got = fingerprint_block_pallas(block_u32)
+    got = fingerprint_block_pallas(block_u32).result()
     check_fingerprint(got, expected_fingerprint_host(file_offset, length,
                                                      salt), file_offset)
     return got

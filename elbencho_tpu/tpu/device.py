@@ -98,8 +98,9 @@ PATH_AUDIT_COUNTERS = (
     # per-op stage counters of the storage->HBM path (docs/telemetry.md
     # "Stage spans"), each sum with its own op count so a reader divides
     # within one record: the time the host blocked in the transfer
-    # ring's completion wait; the on-device verify's sync (transfer +
-    # kernel) and the host's expected fingerprint; and, worker-owned,
+    # ring's completion wait; the on-device verify's hold on the block
+    # loop (kernel dispatch + read of the fingerprint it resolves) and
+    # the host's expected fingerprint; and, worker-owned,
     # the wait of a reaped op behind the earlier ops of its reap batch,
     # and the fused ring's wait in release drains (drains made only to
     # free a held-back slot) over the held slots it let go
@@ -113,6 +114,10 @@ PATH_AUDIT_COUNTERS = (
     ("slot_release_wait_usec", "SlotReleaseWaitUSec",
      "slot_release_wait_usec"),
     ("slot_release_wait_ops", "SlotReleaseWaitOps", "slot_release_wait_ops"),
+    # the most on-device verifies pending at once (dispatched, not yet
+    # checked) when a host_to_device call returned: depth-1 when the
+    # verify overlaps the transfers, 0 at depth 1 (a hwm, MAX merge)
+    ("verify_pending_hwm", "TpuVerifyPendingHwm", "tpu_verify_pending_hwm"),
 )
 
 #: counters owned by the Worker object itself rather than the
@@ -142,7 +147,7 @@ PATH_AUDIT_POOL_ATTRS = frozenset({
 #: single worker ran (~ chips lost along the worst path).
 PATH_AUDIT_MAX_KEYS = frozenset({"TpuPipeInflightHwm", "TpuChipFailovers",
                                  "PoolOccupancyHwm", "IciGbpsHwm",
-                                 "TailP999UsecHwm"})
+                                 "TailP999UsecHwm", "TpuVerifyPendingHwm"})
 
 
 def sum_path_audit_counters(workers) -> dict:
@@ -325,14 +330,18 @@ class TransferPipeline:
         # None keeps the hot path a single attribute test per transfer
         self.stages = None
 
-    def submit(self, submit_fn, op_ns: int = 0, op=None):
+    def submit(self, submit_fn, op_ns: int = 0, op=None, then=None):
         """Issue one transfer (submit_fn() -> device array) into the ring,
         then drain to at most depth-1 in flight: with io_depth rotating
         host buffers, the buffer reused next is then guaranteed drained
         (depth == 1 -> fully synchronous, per-block latency honest).
         ``op_ns`` is the storage-submit stamp of the op whose block this
         is, when its drain is where the block becomes resident (0: not
-        booked here); ``op`` the op's span id."""
+        booked here); ``op`` the op's span id. ``then(arr)``, when
+        given, dispatches device work that reads the block and returns
+        its output, which the ring entry waits on instead: the entry's
+        drain then also ends that work's reads of the block (and of the
+        host buffer an aliasing import still reads)."""
         st = self.stages
         token = st.begin("tpu_dispatch") if st is not None else None
         t0 = time.perf_counter_ns()
@@ -343,14 +352,23 @@ class TransferPipeline:
         if st is not None:
             st.end(token, "tpu_dispatch", "tpu", t0, t1, op)
         # an op whose residency is booked elsewhere (its on-device verify
-        # syncs on it) keeps its id off the entry: the entry's later drain
-        # is then no stage of that op
-        self._ring.append((arr, t1, op_ns, op if op_ns else None))
+        # resolves it) keeps its id off the entry: the entry's later
+        # drain is then no stage of that op
+        self._ring.append((arr if then is None else then(arr), t1, op_ns,
+                           op if op_ns else None))
         if len(self._ring) > self.inflight_hwm:
             self.inflight_hwm = len(self._ring)
         while len(self._ring) >= self.depth:
             self._drain_one(count_stall=True)
         return arr
+
+    def wait_newest_on(self, arr) -> None:
+        """Make the newest in-flight entry wait on ``arr`` instead: work
+        dispatched after that entry's, which in-order device execution
+        completes only after everything dispatched on the entry's block
+        in between (no-op on an empty ring)."""
+        if self._ring:
+            self._ring[-1] = (arr,) + self._ring[-1][1:]
 
     def note_dispatch(self, usec: int) -> None:
         """Account host-side submit cost of a transfer issued outside the
@@ -605,6 +623,13 @@ class TpuWorkerContext:
         self.verify_sync_usec = 0
         self.verify_expect_usec = 0
         self.verify_ops = 0
+        self.verify_pending_hwm = 0
+        # on-device verifies dispatched and not yet checked, oldest first:
+        # (PendingFingerprint, file_offset, length, salt, op_ns, op, the
+        # block's host view). Resolved down to depth-1 with the transfer
+        # ring, so a check lags its block by at most depth-1 blocks
+        from collections import deque
+        self._verify_pending = deque()
         # the op the next host_to_device lands: its storage-submit
         # perf_counter_ns (0: no resident sample) and its span id; set by
         # the worker's block loops, a plain attribute so host_to_device
@@ -636,7 +661,10 @@ class TpuWorkerContext:
         """Drop every reference to device-resident state without touching
         the (possibly dead) chip: in-flight ring entries, staging slots,
         fill pool, speculative D2H blocks, the jitted copy step. No
-        block_until_ready anywhere — the chip may never answer again."""
+        block_until_ready anywhere — the chip may never answer again.
+        The pending verifies are then checked on the host, from the host
+        buffers their blocks were read into (still held: the caller
+        rewrites a buffer only after its check resolved)."""
         self._pipeline.poison()
         self._slot_prev = [None] * self.pipeline_depth
         self._staged_submits = 0
@@ -649,6 +677,7 @@ class TpuWorkerContext:
         self._d2h_spec_miss_streak = 0
         self._last_ingested = None
         self._h2d_agg_fill = 0
+        self._verify_pending_on_host()
 
     def failover_to_chip(self, new_chip_id: int) -> None:
         """Drain-and-poison the failed chip's state, then redirect this
@@ -736,7 +765,9 @@ class TpuWorkerContext:
         pipelines overlap up to --iodepth transfers and only wait when the
         ring is full (documented pipelined mode, SURVEY.md section 7 "TPU
         transfer overlap"). With --tpuverify, the on-device fingerprint
-        check replaces the host-side memcmp.
+        check replaces the host-side memcmp; it is deferred like the
+        transfer's wait, so a bad block raises (naming its offset) up to
+        depth-1 calls later, or at the drain that ends the phase.
 
         Two transfer paths (the cuFileRead-vs-cudaMemcpy split of the
         reference, LocalWorker.cpp:2633-2749):
@@ -757,7 +788,9 @@ class TpuWorkerContext:
         transfer completed (CuFileHandleData register-once discipline,
         reference CuFileHandleData.h:18-73): the drain-to-depth-1 ring
         below provides that to callers that rotate depth buffers, and
-        the fused stream loop holds slots back (holdback_depth).
+        the fused stream loop holds slots back (holdback_depth). A
+        pending verify's block stays in its buffer by the same rule, for
+        the host check a chip failover makes.
         """
         n_words = length // 4
         np_view = np.frombuffer(buf[:n_words * 4], dtype=np.uint32)
@@ -781,42 +814,108 @@ class TpuWorkerContext:
                 self._flush_h2d_batch()
             return
         if verify_salt and self.verify_on_device:
-            # the verify's sync is where this block becomes resident
-            self._transfer_h2d(np_view, 0, self.op_id)
-            fold_fingerprint(self, *self._verify_ingested(
-                file_offset, length, verify_salt))
+            self._verified_h2d(np_view, length, verify_salt, file_offset)
         else:
             self._transfer_h2d(np_view, self.op_submit_ns, self.op_id)
 
-    def _verify_ingested(self, file_offset: int, length: int,
-                         salt: int) -> "tuple[int, int]":
-        """The on-device verify of the block just ingested, timed in two
-        stages: the fingerprint step up to its host sync (the transfer
-        and the kernel), then the host's expected value. The sync's return
-        is the op's resident moment."""
+    def _verified_h2d(self, np_view: np.ndarray, length: int, salt: int,
+                      file_offset: int) -> None:
+        """Land a block and dispatch its fingerprint kernel with no host
+        sync; the block's check waits in the pending FIFO and is resolved
+        once more than depth-1 verifies are pending (at depth 1 within
+        this call), or by a drain. The ring entry waits on the kernel, so
+        the ring's drain guarantee covers the kernel's reads too. On a
+        host-backed device the landed block is the host buffer itself,
+        and a copy of it taken after the call returned reads the buffer
+        too: the previous block's entry then waits on this block's
+        kernel, which in-order execution runs after that copy. The
+        check's resolve is the op's resident moment."""
         from ..ops import verify
-        st = self.stages
         op = self.op_id
-        token = st.begin("verify_sync") if st is not None else None
-        t0 = time.perf_counter_ns()
-        got = verify.fingerprint_block_pallas(self._last_ingested)
-        t1 = time.perf_counter_ns()
-        if st is not None:
-            st.end(token, "verify_sync", "tpu", t0, t1, op)
-            token = st.begin("verify_expect")
-        want = verify.expected_fingerprint_host(file_offset, length, salt)
-        t2 = time.perf_counter_ns()
-        self.verify_sync_usec += (t1 - t0) // 1000
-        self.verify_expect_usec += (t2 - t1) // 1000
-        self.verify_ops += 1
-        if self.op_submit_ns:
-            self._pipeline.resident.append((t1 - self.op_submit_ns) // 1000)
-        if st is not None:
-            st.end(token, "verify_expect", "tpu", t1, t2, op)
-            if op is not None:
-                st.record("op", "op", self.op_submit_ns, t2, op)
-        verify.check_fingerprint(got, want, file_offset)
-        return got
+        queued = []
+
+        def dispatch(arr):
+            st = self.stages
+            token = st.begin("verify_sync") if st is not None else None
+            t0 = time.perf_counter_ns()
+            fp = verify.fingerprint_block_pallas(arr)
+            _d2h_async(fp.array)  # the read-back streams behind the kernel
+            if self.device.platform == "cpu":
+                self._pipeline.wait_newest_on(fp.array)
+            t1 = time.perf_counter_ns()
+            self.verify_sync_usec += (t1 - t0) // 1000
+            if st is not None:
+                st.end(token, "verify_sync", "tpu", t0, t1, op)
+            queued.append((fp, file_offset, length, salt, self.op_submit_ns,
+                           op, np_view))
+            return fp.array
+
+        self._transfer_h2d(np_view, 0, op, then=dispatch)
+        # queued only once landed: a device loss before this point makes
+        # the guarded caller land the block again
+        entry = queued[0]
+        pending = self._verify_pending
+        pending.append(entry)
+        try:
+            self._resolve_verifies(self.pipeline_depth - 1)
+        except Exception:
+            if pending and pending[-1] is entry:
+                pending.pop()  # not checked: the retry lands it again
+            raise
+        if len(pending) > self.verify_pending_hwm:
+            self.verify_pending_hwm = len(pending)
+
+    def _resolve_verifies(self, max_pending: int) -> None:
+        """Check the oldest pending verifies until at most max_pending
+        remain, each timed in two stages: the read of its fingerprint
+        (one host read), then the host's expected value. A bad block
+        raises ValueError naming its own offset."""
+        from ..ops import verify
+        pending = self._verify_pending
+        st = self.stages
+        while len(pending) > max(max_pending, 0):
+            fp, file_offset, length, salt, op_ns, op, _view = pending[0]
+            token = st.begin("verify_sync") if st is not None else None
+            t0 = time.perf_counter_ns()
+            got = fp.result()
+            t1 = time.perf_counter_ns()
+            pending.popleft()  # after the read: a lost chip leaves it queued
+            if st is not None:
+                st.end(token, "verify_sync", "tpu", t0, t1, op)
+                token = st.begin("verify_expect")
+            want = verify.expected_fingerprint_host(file_offset, length, salt)
+            t2 = time.perf_counter_ns()
+            self.verify_sync_usec += (t1 - t0) // 1000
+            self.verify_expect_usec += (t2 - t1) // 1000
+            self.verify_ops += 1
+            if op_ns:
+                self._pipeline.resident.append((t1 - op_ns) // 1000)
+            if st is not None:
+                st.end(token, "verify_expect", "tpu", t1, t2, op)
+                if op is not None:
+                    st.record("op", "op", op_ns, t2, op)
+            verify.check_fingerprint(got, want, file_offset)
+            fold_fingerprint(self, *got)
+
+    def _verify_pending_on_host(self) -> None:
+        """Check every pending verify on the host, from its block's host
+        buffer: the chip that holds the block and its kernel's result may
+        never answer again (chip failover). Same check, same fold."""
+        from ..ops import verify
+        pending = self._verify_pending
+        if pending:
+            from ..toolkits.logger import LOG_NORMAL, log
+            log(LOG_NORMAL, f"NOTE: chip {self.chip_id} lost with "
+                            f"{len(pending)} on-device verify(s) pending; "
+                            f"checking them on the host")
+        while pending:
+            _fp, file_offset, length, salt, _op_ns, _op, view = \
+                pending.popleft()
+            got = verify.fingerprint_host(view)
+            verify.check_fingerprint(
+                got, verify.expected_fingerprint_host(file_offset, length,
+                                                      salt), file_offset)
+            fold_fingerprint(self, *got)
 
     #: read access to the pipeline's ring for tests/diagnostics (the ring
     #: discipline itself lives in TransferPipeline)
@@ -857,18 +956,18 @@ class TpuWorkerContext:
         return self._pipeline.transfer_usec
 
     def _transfer_h2d(self, np_view: np.ndarray, op_ns: int = 0,
-                      op=None) -> None:
+                      op=None, then=None) -> None:
         """One DMA into the in-flight pipeline (a block, or a --tpubatch
         aggregation span). The staged path recycles per-slot HBM staging
         buffers through a donating jitted copy (see _staged_submit); the
-        direct path imports the host buffer as-is (zero-bounce). op_ns
-        and op: see TransferPipeline.submit."""
+        direct path imports the host buffer as-is (zero-bounce). op_ns,
+        op and then: see TransferPipeline.submit."""
         if self.direct and self._h2d_direct_ok:
             arr = self._pipeline.submit(
-                lambda: self._direct_import(np_view), op_ns, op)
+                lambda: self._direct_import(np_view), op_ns, op, then)
         else:
             arr = self._pipeline.submit(
-                lambda: self._staged_submit(np_view), op_ns, op)
+                lambda: self._staged_submit(np_view), op_ns, op, then)
         self._last_ingested = arr  # keep resident (benchmark sink)
 
     def _staged_submit(self, np_view: np.ndarray):
@@ -1006,8 +1105,12 @@ class TpuWorkerContext:
         the buffer-rotation guarantee for callers that reuse host
         buffers on their own schedule — the fused stream loop calls it
         to release a held-back staging slot without waiting for more
-        storage completions."""
+        storage completions. The pending verifies resolve down to the
+        same bound, so a released slot's block was checked too."""
         self._pipeline.drain_to(max_inflight)
+        self._resolve_verifies(max_inflight)
+        if max_inflight <= 0:
+            self._settle_host_buffers()
 
     def reset_path_counters(self) -> None:
         """Zero the H2D/D2H path-audit counters (called from the worker's
@@ -1029,6 +1132,8 @@ class TpuWorkerContext:
         # window so the next phase starts with an empty ring
         self._pipeline.flush(check_budget=False)
         self._pipeline.reset_counters()
+        # a failed phase's unchecked verifies are dropped, never folded
+        self._verify_pending.clear()
         self._d2h_spec.clear()
         self._d2h_spec_miss_streak = 0
         reset_fingerprint(self)
@@ -1039,11 +1144,26 @@ class TpuWorkerContext:
 
     def flush(self) -> None:
         """Drain all pipelined transfers (phase-end completion wait),
-        including a partially-filled --tpubatch aggregation span, then
-        enforce --tpubudget against the measured dispatch overhead."""
+        including a partially-filled --tpubatch aggregation span, check
+        every pending verify, then enforce --tpubudget against the
+        measured dispatch overhead."""
         if self._h2d_agg_fill:
             self._flush_h2d_batch()
-        self._pipeline.flush()
+        self._pipeline.flush(check_budget=False)
+        self._resolve_verifies(0)
+        self._settle_host_buffers()
+        self._pipeline.check_budget()
+
+    def _settle_host_buffers(self) -> None:
+        """After a drain to 0, before the caller reuses every buffer: on a
+        host-backed device the landed block is its host buffer, and a
+        copy of it taken after the call that landed it reads the buffer
+        too. Wait for all work dispatched so far, through one more op on
+        the device, which in-order execution runs after them."""
+        if self._last_ingested is not None \
+                and self.device.platform == "cpu":
+            _get_jax().numpy.zeros((), np.uint32, device=self.device) \
+                .block_until_ready()
 
     def warmup_transfer(self) -> None:
         """Run one staged ingest outside any timed loop so first-use costs
@@ -1213,6 +1333,7 @@ class TpuWorkerContext:
         if self._h2d_agg_fill:
             self._flush_h2d_batch()
         self._pipeline.flush(check_budget=False)
+        self._verify_pending.clear()
         self._last_ingested = None
         self._slot_prev = [None] * self.pipeline_depth
         self._fill_pool = []

@@ -111,9 +111,10 @@ class LocalWorker(Worker):
 
     def finish_phase_stats(self) -> None:
         if self._tpu is not None:
-            # a phase cut by --timelimit leaves transfers in flight:
-            # complete them, so that each H2D op of the phase books its
-            # ready wait and resident latency in this phase's record
+            # a phase cut by --timelimit leaves transfers in flight and
+            # on-device verifies pending: complete them, so that each H2D
+            # op of the phase books its ready wait and resident latency in
+            # this phase's record, and a bad block fails the phase
             self._tpu_guarded(self._tpu.drain_to, 0)
             self._book_resident()
         super().finish_phase_stats()
