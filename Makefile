@@ -4,7 +4,7 @@
 
 .PHONY: all native native-tsan native-asan tsan asan check check-schema \
 	lint test test-fast test-chaos test-scale test-mesh test-obs \
-	test-scenario test-tune test-examples fuzz bench docs clean deb rpm \
+	test-scenario test-tune test-examples fuzz docs clean deb rpm \
 	docker
 
 all: native
@@ -178,9 +178,6 @@ test-tune: native
 # end-to-end example suite against real resources (loopdevs, services)
 test-examples: native
 	tools/test-examples $${BASEDIR:-/tmp}
-
-bench: native
-	python bench.py
 
 docs:
 	python tools/generate-usage-docs

@@ -8,6 +8,9 @@ caught in CI even though this image cannot execute rpmbuild).
 import os
 import re
 import subprocess
+import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPEC = os.path.join(REPO, "packaging", "elbencho-tpu.spec")
@@ -116,3 +119,37 @@ def test_deb_and_docker_reference_existing_paths():
             continue
         assert os.path.exists(os.path.join(REPO, src)), (
             f"Dockerfile COPY references missing path {src}")
+
+
+def _documented_paths() -> "list[str]":
+    """Repo-relative scripts and tools named by a command line in a
+    README.md code block or a Makefile recipe."""
+    with open(os.path.join(REPO, "README.md")) as f:
+        blocks = re.findall(r"^```[^\n]*\n(.*?)^```", f.read(), re.M | re.S)
+    with open(os.path.join(REPO, "Makefile")) as f:
+        recipes = re.findall(r"^\t(.*)$", f.read(), re.M)
+    paths = set()
+    for line in "\n".join(blocks).splitlines() + recipes:
+        for tok in line.split("#", 1)[0].split():
+            tok = tok.rpartition("=")[2]  # --ignore=tests/x.py
+            if tok.startswith("tools/") or tok.endswith((".py", ".sh")):
+                paths.add(tok)
+    return sorted(paths)
+
+
+@pytest.mark.parametrize("path", _documented_paths())
+def test_documented_commands_name_existing_paths(path):
+    """A documented command must not point at a script that is gone."""
+    assert os.path.isfile(os.path.join(REPO, path)), (
+        f"README.md or the Makefile runs missing path {path}")
+
+
+def test_benchmark_entry_point_help():
+    """The cell command README.md documents starts and takes the options
+    it is documented with."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"),
+         "--help"], capture_output=True, text=True, timeout=120, cwd=REPO)
+    assert out.returncode == 0, out.stderr
+    for opt in ("--workload", "--seed", "--seconds"):
+        assert opt in out.stdout

@@ -391,29 +391,6 @@ def test_treescan_requires_treefile(tmp_path, capsys):
     assert rc == 1
 
 
-def _bench_capture_file(tmp_path):
-    """Two bench.py capture lines: one measured (with the pipelined-vs-
-    sync A/B rider), one failed run that measured nothing."""
-    cap = tmp_path / "capture.json"
-    measured = {
-        "metric": "seq read ...", "value": 900.0, "unit": "MiB/s",
-        "utc": "2026-08-01T00:00:00Z",
-        "tpu_dispatch_usec": 1200, "tpu_transfer_usec": 34000,
-        "tpu_pipe_inflight_hwm": 4,
-        "pipeline_ab": {"sync_mibs": 400.0, "pipelined_mibs": 900.0,
-                        "pipelined_vs_sync": 2.25, "sync_dispatch_usec": 800,
-                        "sync_inflight_hwm": 1},
-        "tpustream_ab": {"python_mibs": 700.0, "fused_mibs": 910.0,
-                         "fused_vs_python": 1.3, "fused_ops": 16,
-                         "python_loop_fused_ops": 0}}
-    failed = {
-        "metric": "seq read ...", "value": None, "unit": "MiB/s",
-        "utc": "2026-08-02T00:00:00Z", "pipeline_ab": None,
-        "error": "no TPU backend"}
-    cap.write_text(json.dumps(measured) + "\n" + json.dumps(failed) + "\n")
-    return cap
-
-
 def test_summarize_json_dispatch_split_columns(tmp_path):
     """Phase records report the per-op dispatch-vs-DMA split as columns
     (the --tpubudget observable, chartable per sweep point)."""
@@ -428,30 +405,6 @@ def test_summarize_json_dispatch_split_columns(tmp_path):
     assert float(data[header.index("HBMdisp us/op")]) > 0
 
 
-def test_summarize_json_bench_capture_ab(tmp_path):
-    """bench.py capture lines summarize as the pipelined-vs-sync A/B
-    table; a failed capture shows as unmeasured."""
-    cap = _bench_capture_file(tmp_path)
-    res = _tool("elbencho-tpu-summarize-json", [str(cap)])
-    assert res.returncode == 0, res.stderr
-    assert "pipelined/sync" in res.stdout
-    assert "2.25" in res.stdout and "measured" in res.stdout
-    failed_row = res.stdout.splitlines()[-1]
-    assert "2026-08-02" in failed_row and "unmeasured" in failed_row
-    # the fused-vs-python stream A/B appends to the RIGHT of the existing
-    # columns (consumers keyed by position keep working)
-    assert "fused/python" in res.stdout and "1.3" in res.stdout
-    csv = _tool("elbencho-tpu-summarize-json", [str(cap), "--csv"])
-    header = csv.stdout.splitlines()[0].split(",")
-    assert header[:6] == ["utc", "value MiB/s", "sync MiB/s",
-                          "pipelined MiB/s", "pipelined/sync", "source"]
-    assert header[6:9] == ["python MiB/s", "fused MiB/s",
-                           "fused/python"]
-    # the fixed-buffers A/B + fallback-tier columns append after them
-    assert header[9:] == ["regbuf MiB/s", "percall MiB/s",
-                          "reg/percall", "tier"]
-
-
 def test_chart_tool_rejects_phase_records_cleanly(tmp_path):
     """Ordinary --jsonfile phase records are not chartable — the tool
     must say so instead of misrouting them into the bench-capture path
@@ -464,17 +417,23 @@ def test_chart_tool_rejects_phase_records_cleanly(tmp_path):
     assert "phase-record output" in res.stderr
 
 
-def test_chart_tool_bench_capture_ab(tmp_path):
-    """`elbencho-tpu-chart capture.json` charts the A/B automatically:
-    SYNC and PIPELINED series, no flags needed."""
-    cap = _bench_capture_file(tmp_path)
-    res = _tool("elbencho-tpu-chart", [str(cap)])
-    assert res.returncode == 0, res.stderr
-    assert "MiBPerSecLast [SYNC]" in res.stdout
-    assert "MiBPerSecLast [PIPELINED]" in res.stdout
-    assert "900.0" in res.stdout and "400.0" in res.stdout
-    # the failed capture (no A/B) is skipped with a note
-    assert "skipping capture 2026-08-02" in res.stderr
+@pytest.mark.parametrize("tool, expects", [
+    ("elbencho-tpu-chart", "results CSV (--csvfile)"),
+    ("elbencho-tpu-summarize-json", "--jsonfile phase-record output"),
+])
+def test_tools_refuse_non_phase_json_lines(tmp_path, tool, expects):
+    """JSON lines that are not phase records are refused with a one-line
+    error naming the input the tool reads — no traceback, no table."""
+    path = tmp_path / "metrics.json"
+    path.write_text(json.dumps({"metric": "seq read", "value": 900.0})
+                    + "\n" + json.dumps({"metric": "x", "value": None})
+                    + "\n")
+    res = _tool(tool, [str(path)])
+    assert res.returncode != 0
+    assert expects in res.stderr
+    assert "Traceback" not in res.stderr
+    assert len(res.stderr.strip().splitlines()) == 1, res.stderr
+    assert res.stdout == ""
 
 
 def test_summarize_json_degraded_banner(tmp_path):

@@ -216,8 +216,8 @@ def test_tpubench_pipelined_keeps_transfers_in_flight(tmp_path):
 
 
 def test_tpubench_sync_depth_has_hwm_one(tmp_path):
-    """--tpudepth 1 forces sync mode even with --iodepth > 1 (the A/B
-    baseline of bench.py's pipelined-vs-sync rider)."""
+    """--tpudepth 1 forces sync mode even with --iodepth > 1: one transfer
+    in flight at a time."""
     jsonfile = tmp_path / "out.json"
     rc = main(["--tpubench", "-s", "1M", "-b", "128K", "--iodepth", "4",
                "--tpudepth", "1", "--nolive", "--jsonfile", str(jsonfile)])
