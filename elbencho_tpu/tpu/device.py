@@ -25,6 +25,7 @@ tpu_transfer_usec) and reported by Statistics as "HBM ingest" rows.
 
 from __future__ import annotations
 
+import _thread
 import os
 import threading
 import time
@@ -118,6 +119,11 @@ PATH_AUDIT_COUNTERS = (
     # checked) when a host_to_device call returned: depth-1 when the
     # verify overlaps the transfers, 0 at depth 1 (a hwm, MAX merge)
     ("verify_pending_hwm", "TpuVerifyPendingHwm", "tpu_verify_pending_hwm"),
+    # the chip's group commit of depth-1 staged transfers (H2dGroupCommit):
+    # the blocks it landed, and the list puts that landed them
+    ("h2d_coalesced_blocks", "TpuH2dCoalescedBlocks",
+     "tpu_h2d_coalesced_blocks"),
+    ("h2d_coalesced_puts", "TpuH2dCoalescedPuts", "tpu_h2d_coalesced_puts"),
 )
 
 #: counters owned by the Worker object itself rather than the
@@ -362,6 +368,30 @@ class TransferPipeline:
             self._drain_one(count_stall=True)
         return arr
 
+    def land_grouped(self, group: "H2dGroupCommit", view, op_ns: int = 0,
+                     op=None) -> "_Landing":
+        """Land one block through its chip's group commit and book it as
+        a depth-1 submit and its drain would: its share of the list
+        put's host time as dispatch, the time its thread blocked until
+        the block was resident as one ready wait (queueing behind the
+        put in flight included), put-to-ready as transfer time, and
+        storage submit (``op_ns``) to ready as its resident latency."""
+        landing = group.land(view, self.stages, op)
+        self.dispatch_usec += landing.put_usec
+        self.ops += 1
+        self.transfer_usec += (landing.ready_ns - landing.put_ns) // 1000
+        self.ready_wait_usec += landing.wait_ns // 1000
+        self.ready_wait_ops += 1
+        self.inflight_hwm = max(self.inflight_hwm, 1)
+        st = self.stages
+        if st is not None:
+            st.record("tpu_dma", "tpu", landing.put_ns, landing.ready_ns, op)
+        if op_ns:
+            self.resident.append((landing.ready_ns - op_ns) // 1000)
+            if st is not None and op is not None:
+                st.record("op", "op", op_ns, landing.ready_ns, op)
+        return landing
+
     def wait_newest_on(self, arr) -> None:
         """Make the newest in-flight entry wait on ``arr`` instead: work
         dispatched after that entry's, which in-order device execution
@@ -464,6 +494,136 @@ class TransferPipeline:
         self.ready_wait_usec = 0
         self.ready_wait_ops = 0
         self.resident = array("Q")
+
+
+class _Landing:
+    """One block in a chip's group commit, and what its put left for the
+    thread that lands it."""
+
+    __slots__ = ("view", "gate", "done", "arr", "err", "put_usec", "put_ns",
+                 "ready_ns", "wait_ns", "led")
+
+    def __init__(self, view):
+        self.view = view
+        # released when the block is resolved, or to hand its thread the
+        # next put: a bare lock, the cheapest wait that drops the GIL
+        self.gate = _thread.allocate_lock()
+        self.gate.acquire()
+        self.done = False
+        self.arr = self.err = None
+        self.put_usec = 0     # this block's share of its put's host time
+        self.put_ns = self.ready_ns = 0  # its put returned / was resident
+        self.wait_ns = 0      # its thread's time blocked until then
+        self.led = False      # its thread made the put
+
+
+class H2dGroupCommit:
+    """Group commit of the synchronous (depth-1) staged transfers of one
+    chip, shared by this process's TpuWorkerContexts on it.
+
+    A thread that lands a block queues it. When no put is in flight on
+    the chip, that thread leads: it takes every queued block, its own
+    among them, puts them with ONE list ``jax.device_put`` and waits
+    once for all of them. Threads that queue meanwhile wait, with the
+    GIL released, until their own block is resident. The leader never
+    waits for more blocks to come: a put carries what queued during the
+    one before it (one block when its thread is alone on the chip), so
+    there is no timer and no size. When its put is done, or has raised,
+    the leader hands the next put to the oldest queued block's thread;
+    an error of a put is raised in the thread of each of its blocks."""
+
+    def __init__(self, device):
+        self.device = device
+        # a host-backed put aliases the host buffer, which the caller may
+        # rewrite as soon as its block is resident: put a copy there
+        self._host_backed = device.platform == "cpu"
+        self._lock = threading.Lock()
+        self._queue: list = []
+        self._busy = False
+
+    def land(self, view, stages=None, op=None) -> _Landing:
+        """Land one block's host view on the chip and return its landing
+        once the block is resident (``arr``); raises its put's error."""
+        entry = _Landing(view)
+        with self._lock:
+            self._queue.append(entry)
+            lead = not self._busy
+            self._busy = True
+        if not lead:
+            _timed_wait(entry, stages, op, entry.gate.acquire)
+            lead = not entry.done
+        if lead:
+            self._commit(entry, stages, op)
+        if entry.err is not None:
+            raise entry.err
+        return entry
+
+    def _commit(self, own: _Landing, stages, op) -> None:
+        """Put every queued block, as the leader whose block is ``own``."""
+        jax = _get_jax()
+        with self._lock:
+            batch, self._queue = self._queue, []
+        try:
+            views = [e.view for e in batch]
+            if self._host_backed:
+                views = [np.array(v) for v in views]
+            token = stages.begin("tpu_dispatch") if stages is not None \
+                else None
+            t0 = time.perf_counter_ns()
+            arrs = jax.device_put(views, self.device)
+            t1 = time.perf_counter_ns()
+            if stages is not None:
+                stages.end(token, "tpu_dispatch", "tpu", t0, t1, op,
+                           blocks=len(batch))
+            ready = _timed_wait(own, stages, op, jax.block_until_ready, arrs)
+            share, rest = divmod((t1 - t0) // 1000, len(batch))
+            for e, arr in zip(batch, arrs):
+                e.arr = arr
+                e.put_usec = share
+                e.put_ns = t1
+                e.ready_ns = ready
+            own.put_usec += rest
+            own.led = True
+        except BaseException as err:  # noqa: BLE001 - each member raises it
+            for e in batch:
+                e.err = err
+        finally:
+            with self._lock:
+                nxt = self._queue[0] if self._queue else None
+                self._busy = nxt is not None
+            for e in batch:
+                e.done = True
+                if e is not own:
+                    e.gate.release()
+            if nxt is not None:
+                nxt.gate.release()
+
+
+def _timed_wait(entry: _Landing, stages, op, wait, *args) -> int:
+    """wait(*args) as an ``h2d_wait`` stage of entry's thread, booked in
+    its wait_ns; returns the perf_counter_ns at which it returned."""
+    token = stages.begin("h2d_wait") if stages is not None else None
+    t0 = time.perf_counter_ns()
+    wait(*args)
+    t1 = time.perf_counter_ns()
+    entry.wait_ns += t1 - t0
+    if stages is not None:
+        stages.end(token, "h2d_wait", "tpu", t0, t1, op)
+    return t1
+
+
+_group_commits: dict = {}
+_group_commits_lock = threading.Lock()
+
+
+def h2d_group_commit(device) -> H2dGroupCommit:
+    """The process's group commit for ``device``, made on first use."""
+    key = (device.platform, device.id)
+    with _group_commits_lock:
+        group = _group_commits.get(key)
+        if group is None:
+            group = _group_commits[key] = H2dGroupCommit(device)
+        return group
 
 
 class TpuWorkerContext:
@@ -647,6 +807,16 @@ class TpuWorkerContext:
         self._host_sink = None       # host staging: H2D sink buffer
         self._host_fill_pool: list = []  # host staging: write-source pool
         reset_fingerprint(self)
+        # the chip's group commit, which lands this context's transfers
+        # when they are synchronous staged single blocks: depth 1, no
+        # --tpudirect, --tpubatch or --tpuverify (PATH_AUDIT_COUNTERS:
+        # its blocks and puts)
+        self.h2d_coalesced_blocks = 0
+        self.h2d_coalesced_puts = 0
+        self._group = h2d_group_commit(self.device) \
+            if self.pipeline_depth == 1 and not self.direct \
+            and self.batch_blocks == 1 and not self.verify_on_device \
+            else None
 
     # -- chip failover (--tpufallback; the data-plane analogue of
     # --svctolerant: survive single-chip loss instead of aborting) -------
@@ -691,6 +861,8 @@ class TpuWorkerContext:
         self.chip_id = new_chip_id
         self.device = tpu_devices()[new_chip_id]
         self._key = jax.random.PRNGKey(new_chip_id)
+        if self._group is not None:
+            self._group = h2d_group_commit(self.device)
         self.chip_failovers += 1
         log_error(f"TPU chip {old} lost; worker failed over to chip "
                   f"{new_chip_id} (--tpufallback chip)")
@@ -960,11 +1132,20 @@ class TpuWorkerContext:
         """One DMA into the in-flight pipeline (a block, or a --tpubatch
         aggregation span). The staged path recycles per-slot HBM staging
         buffers through a donating jitted copy (see _staged_submit); the
-        direct path imports the host buffer as-is (zero-bounce). op_ns,
-        op and then: see TransferPipeline.submit."""
+        direct path imports the host buffer as-is (zero-bounce). A
+        depth-1 staged block lands through the chip's group commit
+        instead (H2dGroupCommit), as its own array of one list put, with
+        no slot copy. op_ns, op and then: see TransferPipeline.submit."""
+        group = self._group
         if self.direct and self._h2d_direct_ok:
             arr = self._pipeline.submit(
                 lambda: self._direct_import(np_view), op_ns, op, then)
+        elif group is not None:
+            landing = self._pipeline.land_grouped(group, np_view, op_ns, op)
+            arr = landing.arr
+            self.h2d_staged_ops += 1
+            self.h2d_coalesced_blocks += 1
+            self.h2d_coalesced_puts += landing.led
         else:
             arr = self._pipeline.submit(
                 lambda: self._staged_submit(np_view), op_ns, op, then)
@@ -1172,10 +1353,13 @@ class TpuWorkerContext:
         counters are reset afterwards (call from worker prepare when the
         workload ingests into HBM)."""
         probe = np.zeros(self._num_words, dtype=np.uint32)
-        # depth+1 submits so the first slot is REUSED once: that reuse is
-        # what compiles (and donation-probes) the copy step
-        for _ in range(self.pipeline_depth + 1):
-            self._pipeline.submit(lambda: self._staged_submit(probe))
+        if self._group is not None:
+            self._group.land(probe)  # a list put: no program to compile
+        else:
+            # depth+1 submits so the first slot is REUSED once: that
+            # reuse is what compiles (and donation-probes) the copy step
+            for _ in range(self.pipeline_depth + 1):
+                self._pipeline.submit(lambda: self._staged_submit(probe))
         self._pipeline.flush(check_budget=False)
         self._pipeline.reset_counters()
         self.h2d_staged_ops = 0
