@@ -4,7 +4,9 @@ Every number compared is a count that a sound run holds at 0, so every
 limit is 0 (an exact comparison). The numbers:
 
 - ``bad_words``: 64-bit words of the sampled HBM blocks that differ from
-  the reference at their dataset offset; a sampled call that left no
+  what the call landing them was given: under a ``"dataset"`` check the
+  reference at their dataset offset, under a ``"host"`` check the host
+  bytes the call was given (tap.py); a sampled call that left no
   block, or a block of the wrong size, counts whole.
 - ``bad_fingerprints``: sampled blocks whose ``elbencho_fingerprint``
   result differs from the reference's (sum, xor), or that have none,
@@ -25,14 +27,25 @@ LIMITS = {"bad_words": 0, "bad_fingerprints": 0, "missed_samples": 0,
           "program_errors": 0}
 
 
+def mismatched_host_words(got: np.ndarray, want: np.ndarray) -> int:
+    """64-bit words of ``got`` (any dtype, as landed) that differ from the
+    host bytes ``want``; a block of another size counts whole."""
+    got = np.ascontiguousarray(got).view(np.uint8).reshape(-1)
+    if got.size != want.size or got.size % 8:
+        return want.size // 8 + 1
+    return int(np.count_nonzero(got.view(np.uint64) != want.view(np.uint64)))
+
+
 def compare(tap, record: "dict | None", salt: int, verify: bool,
             program_rc: int) -> "tuple[dict, int]":
     """({name: {"value", "limit"}}, failed samples) for one window."""
     bad_words = bad_fp = bad_blocks = 0
     samples = tap.block_samples()
-    for offset, length, arr, fp in samples:
+    for offset, length, arr, fp, want in samples:
         if arr is None:
             n = length // 8 + 1
+        elif want is not None:
+            n = mismatched_host_words(np.asarray(arr), want)
         else:
             n = reference.mismatched_words(np.asarray(arr), offset, salt)
         bad_words += n

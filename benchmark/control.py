@@ -5,7 +5,10 @@ own size, and print the numbers compared in each run.
         [--faults torn,...] [--seconds 5] [--out DIR]
 
 Each run is its own ``run.py --fault`` process, so one process at a time
-holds the chip. The benchmark's own runs never plant a fault.
+holds the chip. The benchmark's own runs never plant a fault. A fault is
+caught where the run reads ``correct`` false through ``bad_words``: the
+words landed, not only an error of the program; the exit code is 0 only
+where every run was caught.
 """
 
 import argparse
@@ -45,7 +48,8 @@ def main() -> int:
                 lines = proc.stdout.strip().splitlines()
                 result = json.loads(lines[-1]) if lines else {}
                 checks = result.get("checks", {})
-                caught &= result.get("correct") is False
+                caught &= result.get("correct") is False \
+                    and checks.get("bad_words", {}).get("value", 0) > 0
                 print(json.dumps({"cell": cell, "fault": fault, "seed": seed,
                                   "rc": proc.returncode,
                                   "correct": result.get("correct"),

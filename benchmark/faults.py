@@ -2,10 +2,16 @@
 
 Each fault breaks the timed path in the running process only; the
 benchmark's own runs never plant one (``run.py --fault`` is for
-``control.py`` and ``tests/``). The control is ``torn``: each landed
-block keeps the second half of the block landed before it on that
-worker, as when a host buffer is rewritten before its transfer drained,
-the step that would tempt a later PR to drop a wait.
+``control.py`` and ``tests/``, and plants it after the warm-up, under
+the window). The control is ``torn``: each landed block keeps the second
+half of the block landed before it on that worker, as when a host buffer
+is rewritten before its transfer drained, the step that would tempt a
+later PR to drop a wait.
+
+Each patches ``TpuWorkerContext._transfer_h2d``, through which every
+unbatched block of a context lands, on the group commit's path too; the
+patch changes only the view the call is given and passes the rest of
+the call on as it came.
 """
 
 from __future__ import annotations
@@ -19,10 +25,10 @@ def _h2d_fault(transform):
     from elbencho_tpu.tpu.device import TpuWorkerContext
     orig = TpuWorkerContext._transfer_h2d
 
-    def _transfer_h2d(ctx, np_view):
+    def _transfer_h2d(ctx, np_view, *args, **kwargs):
         view = transform(ctx, np_view)
         if view is not None:
-            orig(ctx, view)
+            orig(ctx, view, *args, **kwargs)
     TpuWorkerContext._transfer_h2d = _transfer_h2d
 
 

@@ -7,14 +7,16 @@ Everything runs in this one process, which holds the chip(s):
 
 1. set-up: the TPU runtime, the dataset (elbencho's verify pattern, salted
    from --seed, written into an anonymous shmem file: no disk, nothing
-   left behind), then a warm-up phase of the cell's own traffic on a short
+   left behind; a sparse one, never written, where the traffic's check
+   is "host"), then a warm-up phase of the cell's own traffic on a short
    extent of the file, so that the window compiles nothing;
 2. the window: the program's normal path (the CLI's parse and derive, then
    ``Coordinator``) runs the cell's phase under ``--timelimit``, looped
    with ``--infloop``;
    a tap (tap.py) samples what lands in HBM;
-3. the check (check.py) against the plain reference (reference.py), after
-   the window and after the device memory peak has been read;
+3. the check (check.py) against the plain reference (reference.py), or
+   against the host bytes each sampled call was given, after the window
+   and after the device memory peak has been read;
 4. with ``--trace 1`` the window is traced by the JAX profiler, and the
    per-layer metrics and the breakdown are reduced from the trace.
 
@@ -23,6 +25,18 @@ A cell is a configuration (configs/<name>.json) under a traffic mix
 function of readers.py named after the metric. All are found by the
 names in BENCHMARK.json, so a new cell or metric is new files and
 entries.
+
+A traffic file holds the program's flags (``flags``), whether the cell
+verifies (``verify``), the traced window's length (``trace_seconds``)
+and, optionally:
+
+- ``phase``: the program's phase that the window measures, a name of
+  ``elbencho_tpu.phases.BenchPhase`` (default ``READFILES``); its result
+  records are those whose ``Phase`` is that phase's name (``READ``);
+- ``check``: ``dataset`` (default), the landed blocks against the
+  dataset, or ``host``, against the bytes each landing call was given,
+  for a phase that reads no file (tap.py);
+- ``tap``: the calls the tap wraps (tap.py; default ``tap.DEFAULT_TAP``).
 """
 
 import time
@@ -46,8 +60,6 @@ sys.path.insert(0, BENCH)
 import check  # noqa: E402
 import reference  # noqa: E402
 
-#: the program's phases that a window measures
-MEASURED = ("READFILES",)
 #: counters of the program's records summed over a window's phases
 SUMMED = ("TpuHbmBytes", "TpuDispatchUSec", "TpuH2dStagedOps",
           "TpuH2dDirectOps", "TpuStreamFusedOps")
@@ -153,6 +165,17 @@ class _Compiles:
         return self.built - self.loaded
 
 
+def window_phase(traffic: dict) -> "tuple[str, str]":
+    """(the program's phase that the window measures, the ``Phase`` name
+    of its result records), from the traffic's ``phase``."""
+    from elbencho_tpu.phases import PHASE_NAMES, BenchPhase
+    name = traffic.get("phase", "READFILES")
+    if name not in BenchPhase.__members__:
+        raise Refused(f"no phase {name!r} in the program: "
+                      f"{', '.join(BenchPhase.__members__)}")
+    return name, PHASE_NAMES[BenchPhase[name]]
+
+
 def run_program(argv: "list[str]", on_phase) -> "tuple[int, object]":
     """The CLI's local-run path for argv, with on_phase(coord, phase, run)
     wrapped around each phase; returns (rc, the last phase's results)."""
@@ -179,14 +202,15 @@ def run_program(argv: "list[str]", on_phase) -> "tuple[int, object]":
     return rc, coord._last_phase_results
 
 
-def read_record(path: str) -> "dict | None":
-    """The window's result record: the first measured phase's, with the
-    counters summed over every measured phase."""
+def read_record(path: str, phase_name: str) -> "dict | None":
+    """The window's result record: the first record of the measured
+    phase (``Phase`` == phase_name), with the counters summed over every
+    such record."""
     if not os.path.exists(path):
         return None
     with open(path) as f:
         recs = [json.loads(ln) for ln in f if ln.strip()]
-    recs = [r for r in recs if r.get("Phase") == "READ"]
+    recs = [r for r in recs if r.get("Phase") == phase_name]
     if not recs:
         return None
     merged = dict(recs[0])
@@ -261,12 +285,13 @@ def _main(args) -> int:
     from tap import Tap
     size, block = config["file_size"], config["block_size"]
     n_chips = cell["chips"]
-    tap = Tap(args.seed, block)
-    tap.install()
-    if args.fault:
-        import faults
-        faults.plant(args.fault)
-        log(f"FAULT PLANTED: {args.fault}")
+    measured, record_phase = window_phase(traffic)
+    try:
+        tap = Tap(args.seed, block, traffic.get("check", "dataset"),
+                  traffic.get("tap"))
+        tap.install()
+    except (ValueError, ImportError, AttributeError) as err:
+        raise Refused(f"the traffic's tap or check: {err}") from None
 
     salt = reference.salt_for_seed(args.seed)
     work = tempfile.mkdtemp(prefix="bench-")
@@ -275,11 +300,18 @@ def _main(args) -> int:
         with TraceAnnotation("bench.setup"):
             t = time.monotonic()
             fd = os.memfd_create(f"bench-{cell['name']}")
-            reference.write_dataset(fd, size, salt)
             path = f"/proc/{os.getpid()}/fd/{fd}"
-            log(f"dataset: {size} bytes of the verify pattern in an "
-                f"anonymous shmem file (memfd, no disk write), salt {salt}, "
-                f"written in {time.monotonic() - t:.3f} s")
+            if tap.check == "dataset":
+                reference.write_dataset(fd, size, salt)
+                log(f"dataset: {size} bytes of the verify pattern in an "
+                    f"anonymous shmem file (memfd, no disk write), salt "
+                    f"{salt}, written in {time.monotonic() - t:.3f} s")
+            else:
+                # the program sizes its phase against the path's size;
+                # a sparse memfd holds no page until one is written
+                os.ftruncate(fd, size)
+                log(f"dataset: none (host check); the path is a sparse "
+                    f"memfd of {size} bytes, nothing written")
         base = [*traffic["flags"], *config["flags"], "-b", str(block),
                 "-s", str(size), "--nolive"]
         if traffic["verify"]:
@@ -288,7 +320,7 @@ def _main(args) -> int:
         w: dict = {}   # the window: start, end, compiles, trace state
 
         def on_phase(coord, phase, body):
-            if phase.name not in MEASURED or "limit" not in w:
+            if phase.name != measured or "limit" not in w:
                 body()
                 return
             mgr = coord.manager
@@ -331,6 +363,10 @@ def _main(args) -> int:
             f"{compiles.count} compiles so far, "
             f"{time.monotonic() - t:.3f} s")
         tap.reset(args.seed)
+        if args.fault:
+            import faults
+            faults.plant(args.fault)
+            log(f"FAULT PLANTED under the window: {args.fault}")
         w["limit"] = traffic["trace_seconds"] if traced else args.seconds
         w["trace_dir"] = os.path.join(work, "trace")
         win_json = os.path.join(work, "window.json")
@@ -348,7 +384,7 @@ def _main(args) -> int:
                     w["annotation"].__exit__(None, None, None)
                     if traced:
                         jax.profiler.stop_trace()
-        run.record = read_record(win_json)
+        run.record = read_record(win_json, record_phase)
         if "start" in w:
             run.setup_s = w["start"] - T0
             run.window_s = w["end"] - w["start"]
@@ -358,6 +394,11 @@ def _main(args) -> int:
             f"{w.get('compiles', 'no')} compiles inside it, setup "
             f"{run.setup_s:.6f} s, native engine ops "
             f"{(run.record or {}).get('TpuStreamFusedOps', 0)}")
+        if "start" in w:
+            log(f"tap: {tap.spent_s:.6f} s of its own work in the window, "
+                f"summed over threads: "
+                f"{100 * tap.spent_s / max(run.window_s, 1e-9):.4f}% of it, "
+                f"{tap.calls} calls")
         if "cpu1" in w:
             busy = sum(w["cpu1"][:2]) - sum(w["cpu0"][:2])
             log(f"host: this process used {busy:.3f} CPU s in the window "

@@ -15,6 +15,7 @@ REPO = os.path.dirname(BENCH)
 TINY = {
     "seqread-16m": (256 << 20, ["-s", str(48 << 20)]),
     "randread-4k": (64 << 20, ["--randamount", str(1 << 20)]),
+    "h2d-ceiling": (256 << 20, ["-s", str(48 << 20)]),
 }
 
 
